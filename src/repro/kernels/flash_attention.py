@@ -21,6 +21,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -116,19 +117,12 @@ def flash_attention_tpu(q, k, v, *, causal: bool = True, window=None,
         out_specs=pl.BlockSpec((1, q_block, hd), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * K * G, Sp, hd), q.dtype),
         scratch_shapes=[
-            _vmem((q_block,), jnp.float32),      # running max  m
-            _vmem((q_block,), jnp.float32),      # running norm l
-            _vmem((q_block, hd), jnp.float32),   # accumulator
+            pltpu.VMEM((q_block,), jnp.float32),      # running max  m
+            pltpu.VMEM((q_block,), jnp.float32),      # running norm l
+            pltpu.VMEM((q_block, hd), jnp.float32),   # accumulator
         ],
         interpret=interpret,
     )(qf, kf, vf)
     out = out.reshape(B, K, G, Sp, hd).transpose(0, 3, 1, 2, 4)
     return out[:, :S]
 
-
-def _vmem(shape, dtype):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.VMEM(shape, dtype)
-    except Exception:  # pragma: no cover — non-TPU builds
-        return pl.MemorySpace.ANY  # type: ignore[attr-defined]

@@ -1,9 +1,11 @@
 """Pallas TPU tiled O(N^2) gravity kernel (the paper's N-body example app).
 
 Grid: (i-tiles, j-tiles).  Each step loads a [bi, 3] block of target bodies
-and a [bj, 3] block of sources into VMEM and accumulates forces in an f32
-VMEM scratch tile; the all-pairs structure is the same "stream the second
-operand" pattern as flash attention, so VMEM stays O(tile).
+and a [3, bj] block of sources (the positions transposed, one body per lane)
+into VMEM and accumulates forces in an f32 VMEM scratch tile; the all-pairs
+structure is the same "stream the second operand" pattern as flash
+attention, so VMEM stays O(tile).  The pair math runs per coordinate on
+[bi, bj] tiles: a target coordinate is a column, a source coordinate a row.
 
 Positions are padded to tile multiples; padded sources get zero mass via an
 index mask.
@@ -16,8 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from .flash_attention import _vmem
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(pi_ref, pj_ref, o_ref, acc_ref, *, soft: float, bj: int, N: int):
@@ -29,14 +30,15 @@ def _kernel(pi_ref, pj_ref, o_ref, acc_ref, *, soft: float, bj: int, N: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     pi = pi_ref[...].astype(jnp.float32)            # [bi, 3]
-    pj = pj_ref[...].astype(jnp.float32)            # [bj, 3]
-    d = pj[None, :, :] - pi[:, None, :]             # [bi, bj, 3]
-    r2 = jnp.sum(d * d, axis=-1) + soft
+    pj = pj_ref[...].astype(jnp.float32)            # [3, bj]
+    d = [pj[c:c + 1, :] - pi[:, c:c + 1] for c in range(3)]   # 3 x [bi, bj]
+    r2 = d[0] * d[0] + d[1] * d[1] + d[2] * d[2] + soft
     inv = jax.lax.rsqrt(r2)
     w = inv * inv * inv                             # 1 / r^3
     jpos = j * bj + jax.lax.broadcasted_iota(jnp.int32, w.shape, 1)
     w = jnp.where(jpos < N, w, 0.0)                 # mask padded sources
-    acc_ref[...] += jnp.einsum("ijc,ij->ic", d, w)
+    for c in range(3):
+        acc_ref[:, c:c + 1] += jnp.sum(d[c] * w, axis=1, keepdims=True)
 
     @pl.when(j == nj - 1)
     def _fin():
@@ -60,11 +62,11 @@ def nbody_forces_tpu(p_all, *, tile_i: int = 256, tile_j: int = 256,
         grid=grid,
         in_specs=[
             pl.BlockSpec((ti, 3), lambda i, j: (i, 0)),
-            pl.BlockSpec((tj, 3), lambda i, j: (j, 0)),
+            pl.BlockSpec((3, tj), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((ti, 3), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Np, 3), p_all.dtype),
-        scratch_shapes=[_vmem((ti, 3), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((ti, 3), jnp.float32)],
         interpret=interpret,
-    )(pp, pp)
+    )(pp, pp.T)
     return out[:N]

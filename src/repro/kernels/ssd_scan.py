@@ -11,6 +11,11 @@ Per grid step (one head, one chunk of q timesteps):
     y_intra  = ((C B^T) * L) x
     y_inter  = diag(exp(cumsum(a))) C h_prev
     h_new    = exp(total) h_prev + sum_j decay_to_end[j] B_j x_j^T
+
+The chunk-local cumsum of ``a`` is taken outside the kernel and laid out
+[heads, chunks, q], one block per head (a [1, q] block would break the TPU's
+(8, 128) tiling); each step reads its chunk's row.  B and C are shared by
+the heads of a batch row and are indexed, not broadcast.
 """
 
 from __future__ import annotations
@@ -20,11 +25,18 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _vmem
+
+def _dot(lhs, rhs, dims):
+    # f32 operands at f32 precision: Mosaic's default contracts f32 in a
+    # single bf16 pass, which misses the f32 reference by percents
+    return jax.lax.dot_general(lhs, rhs, dims,
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
 
 
-def _kernel(x_ref, a_ref, b_ref, c_ref, y_ref, state_ref, h_ref, *,
+def _kernel(x_ref, cs_ref, b_ref, c_ref, y_ref, state_ref, h_ref, *,
             q: int, p: int, n: int):
     ci = pl.program_id(1)
     nc = pl.num_programs(1)
@@ -34,32 +46,28 @@ def _kernel(x_ref, a_ref, b_ref, c_ref, y_ref, state_ref, h_ref, *,
         h_ref[...] = jnp.zeros_like(h_ref)
 
     x = x_ref[0].astype(jnp.float32)          # [q, p]
-    a = a_ref[0].astype(jnp.float32)          # [q]
+    cs_row = cs_ref[0, pl.ds(ci, 1), :]       # [1, q] chunk-local cumsum(a)
+    cs = cs_row.T                             # [q, 1]
     B = b_ref[0].astype(jnp.float32)          # [q, n]
     C = c_ref[0].astype(jnp.float32)          # [q, n]
 
-    cs = jnp.cumsum(a)                        # [q]
-    seg = cs[:, None] - cs[None, :]           # [q, q]
+    seg = cs - cs_row                         # [q, q]
     ii = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
     Lmat = jnp.where(jj <= ii, jnp.exp(seg), 0.0)
 
-    scores = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32) * Lmat
-    y = jax.lax.dot_general(scores, x, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
+    scores = _dot(C, B, (((1,), (1,)), ((), ()))) * Lmat
+    y = _dot(scores, x, (((1,), (0,)), ((), ())))
 
     h_prev = h_ref[...]                       # [p, n]
-    decay_from_start = jnp.exp(cs)            # [q]
-    y += (decay_from_start[:, None]
-          * jax.lax.dot_general(C, h_prev, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32))
+    y += jnp.exp(cs) * _dot(C, h_prev, (((1,), (1,)), ((), ())))
 
-    decay_to_end = jnp.exp(cs[-1] - cs)       # [q]
-    state_upd = jax.lax.dot_general(x * decay_to_end[:, None], B,
-                                    (((0,), (0,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-    h_ref[...] = jnp.exp(cs[-1]) * h_prev + state_upd
+    # cumsum at the chunk's end, [1, 1]; a lane reduction rather than a
+    # slice at lane q-1, which Mosaic cannot broadcast back over [p, n]
+    total = jnp.sum(jnp.where(jj[:1] == q - 1, cs_row, 0.0), axis=1,
+                    keepdims=True)
+    state_upd = _dot(x * jnp.exp(total - cs), B, (((0,), (0,)), ((), ())))
+    h_ref[...] = jnp.exp(total) * h_prev + state_upd
 
     y_ref[0] = y.astype(y_ref.dtype)
 
@@ -80,11 +88,10 @@ def ssd_scan_tpu(x, a, B, C, *, chunk: int = 64, interpret: bool = False):
     n = B.shape[-1]
     assert s % chunk == 0
     nc = s // chunk
-    # fold (batch, head); broadcast B/C across heads
+    # fold (batch, head)
     xf = x.transpose(0, 2, 1, 3).reshape(b * h, s, p)
-    af = a.transpose(0, 2, 1).reshape(b * h, s)
-    Bf = jnp.broadcast_to(B[:, None], (b, h, s, n)).reshape(b * h, s, n)
-    Cf = jnp.broadcast_to(C[:, None], (b, h, s, n)).reshape(b * h, s, n)
+    cs = jnp.cumsum(a.astype(jnp.float32).transpose(0, 2, 1)
+                    .reshape(b * h, nc, chunk), axis=-1)
 
     grid = (b * h, nc)
     y, state = pl.pallas_call(
@@ -92,9 +99,9 @@ def ssd_scan_tpu(x, a, B, C, *, chunk: int = 64, interpret: bool = False):
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, chunk, p), lambda g, c: (g, c, 0)),
-            pl.BlockSpec((1, chunk), lambda g, c: (g, c)),
-            pl.BlockSpec((1, chunk, n), lambda g, c: (g, c, 0)),
-            pl.BlockSpec((1, chunk, n), lambda g, c: (g, c, 0)),
+            pl.BlockSpec((1, nc, chunk), lambda g, c: (g, 0, 0)),
+            pl.BlockSpec((1, chunk, n), lambda g, c: (g // h, c, 0)),
+            pl.BlockSpec((1, chunk, n), lambda g, c: (g // h, c, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, p), lambda g, c: (g, c, 0)),
@@ -104,9 +111,9 @@ def ssd_scan_tpu(x, a, B, C, *, chunk: int = 64, interpret: bool = False):
             jax.ShapeDtypeStruct((b * h, s, p), x.dtype),
             jax.ShapeDtypeStruct((b * h, p, n), jnp.float32),
         ],
-        scratch_shapes=[_vmem((p, n), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
-    )(xf, af, Bf, Cf)
+    )(xf, cs, B, C)
     y = y.reshape(b, h, s, p).transpose(0, 2, 1, 3)
     state = state.reshape(b, h, p, n)
     return y, state

@@ -4,7 +4,8 @@ Grid over row tiles.  Pallas block index maps are in whole-block units, so
 overlapping halo windows are not directly expressible; instead the +-1-row
 neighbours are provided as two pre-shifted, tile-aligned input arrays (XLA
 fuses the shifts into cheap copies) and each grid step works entirely on
-[tile, W] VMEM blocks.  Column neighbours are in-block rolls.
+[tile, W] VMEM blocks.  Column neighbours are in-block rolls.  A block
+spans the full width, so the row tile shrinks as the field widens.
 
 Boundary rows/columns are clamped to zero (Dirichlet), matching
 ``ref.wave_step_ref``.
@@ -37,12 +38,24 @@ def _kernel(um_ref, u_ref, up_ref, dn_ref, o_ref, *, c: float, tile: int,
     o_ref[...] = jnp.where(interior, un, 0.0).astype(o_ref.dtype)
 
 
+VMEM_BUDGET = 12 * 2**20
+BLOCKS_AND_TEMPS = 16          # 5 blocks x 2 buffers + ~6 [tile, W] temps
+
+
+def _row_tile(W: int) -> int:
+    """Largest row tile (a multiple of 8, at most 128) whose five
+    double-buffered [tile, W] f32 blocks and the kernel's temporaries fit
+    the 16 MiB of VMEM a kernel may use by default."""
+    return max(8, min(128, VMEM_BUDGET // (W * 4 * BLOCKS_AND_TEMPS) // 8 * 8))
+
+
 @functools.partial(jax.jit, static_argnames=("c", "tile", "interpret"))
-def wave_step_tpu(um, u, *, c: float = 0.25, tile: int = 128,
+def wave_step_tpu(um, u, *, c: float = 0.25, tile=None,
                   interpret: bool = False):
-    """One wave step: um/u [H,W] -> next field [H,W]."""
+    """One wave step: um/u [H,W] -> next field [H,W].  ``tile`` rows per grid
+    step; by default the largest that fits VMEM at this width."""
     H, W = u.shape
-    tile = min(tile, H)
+    tile = min(tile or _row_tile(W), H)
     Hp = -(-H // tile) * tile
     pad = ((0, Hp - H), (0, 0))
     umpad = jnp.pad(um, pad)

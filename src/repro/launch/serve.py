@@ -30,8 +30,10 @@ import numpy as np
 
 def _main_model(args: argparse.Namespace) -> None:
     from repro.configs import get_config
+    from repro.launch.compile_cache import use_persistent_cache
     from repro.runtime import ServeLoop
 
+    use_persistent_cache()
     cfg = get_config(args.arch, reduced=not args.full)
     sl = ServeLoop(cfg, max_batch=args.max_batch, max_len=256)
     rng = np.random.default_rng(0)

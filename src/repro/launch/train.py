@@ -1,12 +1,14 @@
 """End-to-end training driver.
 
     PYTHONPATH=src python -m repro.launch.train --arch qwen2-1.5b \
-        --steps 100 --batch 8 --seq 128 [--reduced] [--ckpt DIR]
+        --steps 100 --batch 8 --seq 128 [--full] [--ckpt DIR]
 
-``--reduced`` (default on CPU) trains the smoke-sized family variant; the
-full configs are for TPU deployments (and are exercised via the dry-run).
-The loop is the IDAG-orchestrated TrainLoop: data prefetch, step dispatch
-and async checkpointing overlap via the paper's scheduling machinery.
+By default it trains the smoke-sized variant of the family; ``--full``
+trains the published config, which needs an accelerator (``mamba2-370m``
+at batch 4 x seq 1024 fits one TPU v5e chip).  The loop is the
+IDAG-orchestrated TrainLoop: data prefetch, step dispatch and async
+checkpointing overlap via the paper's scheduling machinery.  Compiled steps
+go to the persistent cache of ``launch/compile_cache.py``.
 """
 
 from __future__ import annotations
@@ -29,8 +31,10 @@ def main() -> None:
     args = ap.parse_args()
 
     from repro.configs import get_config
+    from repro.launch.compile_cache import use_persistent_cache
     from repro.runtime import TrainLoop
 
+    use_persistent_cache()
     cfg = get_config(args.arch, reduced=not args.full)
     print(f"[train] {cfg.name} ({'full' if args.full else 'reduced'}): "
           f"{cfg.param_count() / 1e6:.1f}M params, "

@@ -14,9 +14,10 @@ checkpoint writes with device compute:
     serializing snapshots against parameter updates without blocking
     subsequent steps (the save itself is async in CheckpointManager).
 
-On this CPU container the jitted step runs on the host; on a TPU deployment
-the same loop drives pjit-compiled steps over the production mesh —
-inside-step distribution belongs to XLA (see DESIGN.md §2).
+Each step task dispatches one ``jax.jit`` step on JAX's default device (a
+TPU chip where one is attached, else the CPU), donating the parameters and
+optimizer state it replaces.  The loop spans one device; inside-step
+distribution belongs to XLA (see DESIGN.md §2).
 """
 
 from __future__ import annotations

@@ -1,0 +1,108 @@
+"""Ahead-of-time compiles for one TPU v5e chip, with no chip attached.
+
+The TPU compiler is installed with JAX and compiles for a described
+topology.  These tests catch what interpret mode cannot: Mosaic refusing a
+block layout or an in-kernel op, a kernel asking for more VMEM than it may
+use, and a train step that does not fit the chip's 16 GiB of HBM.  The
+kernels compile at the sizes ``chip_smoke.py`` runs them at.
+
+The topology is described inside a module fixture, never at import: the
+TPU library may be loaded by one process at a time, and only the worker
+that runs this file loads it.  The persistent compilation cache is off
+around these compiles, since what they write cannot be read back without a
+chip.
+"""
+
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels.flash_attention import flash_attention_tpu
+from repro.kernels.nbody import nbody_forces_tpu
+from repro.kernels.ssd_scan import ssd_scan_tpu
+from repro.kernels.stencil5 import wave_step_tpu
+from repro.launch.steps import make_train_step
+from repro.models import build_model
+from repro.optim import adamw_init
+
+HBM_BYTES = 16 * 2**30          # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means no compiler
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _spec(sharding, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile_kernel(fn, *args):
+    exe = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in exe.as_text()
+    return exe
+
+
+def test_nbody_compiles_at_65536_bodies(one_chip):
+    _compile_kernel(nbody_forces_tpu, _spec(one_chip, (65536, 3)))
+
+
+def test_wave_step_compiles_at_8192_square(one_chip):
+    f = _spec(one_chip, (8192, 8192))
+    _compile_kernel(wave_step_tpu, f, f)
+
+
+def test_ssd_scan_compiles_at_mamba2_370m_widths(one_chip):
+    b, s, h, p, n = 2, 2048, 32, 64, 128
+    _compile_kernel(lambda x, a, B, C: ssd_scan_tpu(x, a, B, C, chunk=64),
+                    _spec(one_chip, (b, s, h, p)), _spec(one_chip, (b, s, h)),
+                    _spec(one_chip, (b, s, n)), _spec(one_chip, (b, s, n)))
+
+
+def test_flash_attention_compiles_at_qwen2_1_5b_widths(one_chip):
+    b, s, k, g, hd = 2, 2048, 2, 6, 128
+    _compile_kernel(flash_attention_tpu,
+                    _spec(one_chip, (b, s, k, g, hd), jnp.bfloat16),
+                    _spec(one_chip, (b, s, k, hd), jnp.bfloat16),
+                    _spec(one_chip, (b, s, k, hd), jnp.bfloat16))
+
+
+def test_mamba2_370m_train_step_fits_one_chip(one_chip):
+    """Full widths, depth cut to 2 layers to keep the compile short; the
+    layers are a scan, so depth scales the parameter bytes and not the
+    program."""
+    cfg = dataclasses.replace(get_config("mamba2-370m"), num_layers=2)
+    model = build_model(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: _spec(one_chip, s.shape, s.dtype), tree)
+
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    opt = jax.eval_shape(adamw_init, params)
+    toks = _spec(one_chip, (4, 1024), jnp.int32)
+    step = jax.jit(make_train_step(model), donate_argnums=(0, 1))
+    exe = step.lower(on_chip(params), on_chip(opt),
+                     {"tokens": toks, "labels": toks}).compile()
+    ma = exe.memory_analysis()
+    used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    assert 0 < used < HBM_BYTES
