@@ -5,6 +5,10 @@ paper visualizes: main-thread task submission, scheduler-thread graph
 generation, and per-lane instruction execution.  ``overlap_fraction``
 quantifies how much scheduling work was hidden behind execution — the
 paper's headline qualitative claim for the concurrent architecture.
+
+The same span log is the trainer's flight recorder (``flight_recorder``): a
+bounded ``Tracer`` into which ``TrainLoop`` writes one ``scope`` per stage of
+each step, mirrored into the profiler's trace where the writer asks.
 """
 
 from __future__ import annotations
@@ -12,14 +16,14 @@ from __future__ import annotations
 import json
 import threading
 import time
-from collections import defaultdict
+from collections import defaultdict, deque
 from dataclasses import dataclass
-from typing import Optional
+from typing import Callable, Optional
 
 from .observability import InstrRecord
 
 
-@dataclass
+@dataclass(slots=True)
 class Span:
     lane: str          # "main" | "sched-N0" | "N0.D1.q0" | "N0.host" | ...
     kind: str          # "task" | "cdag" | "idag" | instruction type
@@ -32,7 +36,12 @@ class Span:
 
 
 class Tracer:
-    """Thread-safe append-only span log."""
+    """Thread-safe append-only span log.
+
+    With ``capacity`` set, the spans, records, instants and each counter
+    track are rings that keep only the newest ``capacity`` entries, so a
+    recorder that lives as long as a training job stays bounded.
+    """
 
     # executors skip per-instruction issue() callbacks for this tracer:
     # execution spans are derived from completion records, so issue-time
@@ -40,26 +49,28 @@ class Tracer:
     # Duck-typed tracer doubles that want live issue events leave this True.
     issue_events = False
 
-    def __init__(self, *, record_sample: int = 1) -> None:
+    def __init__(self, *, record_sample: int = 1,
+                 capacity: Optional[int] = None) -> None:
         self._lock = threading.Lock()
+        log = list if capacity is None else (lambda: deque(maxlen=capacity))
         # 1-in-N InstrRecord capture: with ``record_sample=N > 1`` only every
         # Nth completion is recorded, cutting traced issue overhead at the
         # cost of honestly widened gaps in the critical-path report (the
         # analyzer's ``unattributed_us`` absorbs the dropped records)
         self.record_sample = max(1, int(record_sample))
         self.records_sampled_out = 0
-        self.spans: list[Span] = []
+        self.spans: list[Span] = log()
         # counter tracks: name -> [(t, value)] — used for the per-memory
         # byte high-water marks the budget acceptance checks read
-        self.counters: dict[str, list[tuple[float, float]]] = defaultdict(list)
+        self.counters: dict[str, list[tuple[float, float]]] = defaultdict(log)
         # point-in-time events (fault injections, retransmits, aborts):
         # (lane, name, t, args) — rendered as Perfetto instant ("i") events
-        self.instants: list[tuple[str, str, float, dict]] = []
+        self.instants: list[tuple[str, str, float, dict]] = log()
         self._open: dict[tuple[int, int], float] = {}   # (node, iid) -> t_issue
         # per-instruction execution records (timing breakdown + trace
         # context); instruction spans are derived from these on demand, so
         # the executor's completion path appends exactly one object
-        self.records: list[InstrRecord] = []
+        self.records: list[InstrRecord] = log()
         self.epoch = time.perf_counter()
 
     def now(self) -> float:
@@ -69,6 +80,15 @@ class Tracer:
              meta: Optional[dict] = None) -> None:
         with self._lock:
             self.spans.append(Span(lane, kind, name, t0, t1, meta))
+
+    def scope(self, lane: str, name: str, *,
+              annotate: Optional[Callable] = None, **meta) -> "_Scope":
+        """Time a ``with`` block as a span ``name`` of ``lane`` carrying
+        ``meta`` (e.g. ``step=t``).  ``annotate(name, **meta)``, where given,
+        is entered around it: the trainer passes the profiler's annotation,
+        so the span also lands in a profiler trace, on the device's clock."""
+        return _Scope(self, lane, name,
+                      annotate(name, **meta) if annotate else None, meta)
 
     def counter(self, name: str, value: float) -> None:
         """Record one sample of a named counter (e.g. ``N0.M2.bytes``)."""
@@ -337,3 +357,46 @@ class Tracer:
             lines.append(f"{lane:>16} |{''.join(row)}|")
         lines.append(f"{'':>16}  0{'':{width - 10}}{tmax * 1e3:8.2f}ms")
         return "\n".join(lines)
+
+
+class _Scope:
+    """One ``Tracer.scope`` in flight (a class rather than a generator-based
+    context manager, which costs more: a trainer step opens six)."""
+
+    __slots__ = ("tracer", "lane", "name", "ann", "meta", "t0")
+
+    def __init__(self, tracer: Tracer, lane: str, name: str, ann, meta: dict):
+        self.tracer, self.lane, self.name = tracer, lane, name
+        self.ann, self.meta = ann, meta
+
+    def __enter__(self) -> None:
+        if self.ann is not None:
+            self.ann.__enter__()
+        self.t0 = time.perf_counter()
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.perf_counter()
+        e = self.tracer.epoch
+        # one append, atomic under the interpreter lock: no Tracer lock
+        self.tracer.spans.append(Span(self.lane, "scope", self.name,
+                                      self.t0 - e, t1 - e, self.meta or None))
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+
+
+FLIGHT_RECORDER_CAPACITY = 65536
+_flight_recorder: Optional[Tracer] = None
+_flight_lock = threading.Lock()
+
+
+def flight_recorder() -> Tracer:
+    """The process's trainer flight recorder, built on first use: a
+    ``Tracer`` bounded to the newest ``FLIGHT_RECORDER_CAPACITY`` spans.
+    Process-wide, as ``jax.profiler``'s session and ``logging``'s root
+    logger are; every ``TrainLoop`` not given a ``tracer=`` writes into it.
+    Tracer time plus ``epoch`` is ``time.perf_counter()``."""
+    global _flight_recorder
+    with _flight_lock:
+        if _flight_recorder is None:
+            _flight_recorder = Tracer(capacity=FLIGHT_RECORDER_CAPACITY)
+        return _flight_recorder

@@ -28,6 +28,7 @@ def segsum(a):
     return jnp.where(mask, out, -jnp.inf)
 
 
+@jax.named_scope("ssd")
 def ssd_chunked(x, a, B, C, chunk: int):
     """SSD scan (discrete) — x:[b,s,h,p] a:[b,s,h] B,C:[b,s,n] (1 group).
 
@@ -86,6 +87,7 @@ def ssd_chunked(x, a, B, C, chunk: int):
     return y, hlast
 
 
+@jax.named_scope("conv")
 def causal_conv(x, w, b):
     """Depthwise causal conv, width W: x [B,S,C], w [W,C], b [C]."""
     W = w.shape[0]
@@ -139,7 +141,8 @@ class Mamba2LM:
         """in_proj + split + conv; returns z, xs, B, C, dt."""
         cfg = self.cfg
         di, n, h = self.d_inner, cfg.ssm_state, self.nheads
-        zxbcdt = L.linear(lp["in_proj"], x)
+        with jax.named_scope("in_proj"):
+            zxbcdt = L.linear(lp["in_proj"], x)
         z, xBC, dt = jnp.split(zxbcdt, [di, di + self.conv_dim], axis=-1)
         return z, xBC, dt
 
@@ -147,7 +150,8 @@ class Mamba2LM:
         cfg = self.cfg
         Bsz, S, _ = x.shape
         di, n, h = self.d_inner, cfg.ssm_state, self.nheads
-        hin = L.rms_norm(lp["ln"], x, cfg.norm_eps)
+        with jax.named_scope("norm"):
+            hin = L.rms_norm(lp["ln"], x, cfg.norm_eps)
         z, xBC, dt = self._mix_in(lp, hin)
         xBC = jax.nn.silu(causal_conv(xBC, lp["conv_w"].astype(x.dtype),
                                       lp["conv_b"].astype(x.dtype)))
@@ -161,28 +165,34 @@ class Mamba2LM:
                            cfg.ssm_chunk)
         y = y + xh * lp["D"].astype(x.dtype)[:, None]
         y = y.reshape(Bsz, S, di)
-        y = L.rms_norm(lp["norm"], y * jax.nn.silu(z), cfg.norm_eps)
-        return x + L.linear(lp["out_proj"], y)
+        with jax.named_scope("norm"):
+            y = L.rms_norm(lp["norm"], y * jax.nn.silu(z), cfg.norm_eps)
+        with jax.named_scope("out_proj"):
+            return x + L.linear(lp["out_proj"], y)
 
     # -- forward / loss --------------------------------------------------------
     def forward(self, params, ids):
         cfg = self.cfg
-        x = L.embed(params["embed"], ids).astype(cfg.adt)
+        with jax.named_scope("embed"):
+            x = L.embed(params["embed"], ids).astype(cfg.adt)
 
         def body(x, lp):
             return self._block_seq(lp, x), None
 
         body_fn = jax.checkpoint(body) if cfg.remat else body
         x, _ = jax.lax.scan(body_fn, x, params["layers"])
-        x = L.rms_norm(params["ln_f"], x, cfg.norm_eps)
-        if cfg.tie_embeddings:
-            return L.unembed(params["embed"], x), 0.0
-        return L.linear(params["head"], x).astype(jnp.float32), 0.0
+        with jax.named_scope("norm"):
+            x = L.rms_norm(params["ln_f"], x, cfg.norm_eps)
+        with jax.named_scope("unembed"):
+            if cfg.tie_embeddings:
+                return L.unembed(params["embed"], x), 0.0
+            return L.linear(params["head"], x).astype(jnp.float32), 0.0
 
     def loss(self, params, batch):
         logits, _ = self.forward(params, batch["tokens"])
-        return L.cross_entropy(logits[:, :-1], batch["labels"][:, 1:],
-                               batch.get("mask", None))
+        with jax.named_scope("cross_entropy"):
+            return L.cross_entropy(logits[:, :-1], batch["labels"][:, 1:],
+                                   batch.get("mask", None))
 
     # -- decode (recurrent; O(1) in sequence length) ------------------------------
     def init_cache(self, B: int, max_len: int) -> dict:

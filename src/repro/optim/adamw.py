@@ -24,6 +24,7 @@ def adamw_init(params):
             "step": jnp.zeros((), jnp.int32)}
 
 
+@jax.named_scope("adamw")
 def adamw_update(params, grads, state, *, lr=3e-4, b1=0.9, b2=0.95,
                  eps=1e-8, weight_decay=0.1, grad_clip=1.0):
     step = state["step"] + 1

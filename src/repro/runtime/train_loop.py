@@ -18,10 +18,28 @@ Each step task dispatches one ``jax.jit`` step on JAX's default device (a
 TPU chip where one is attached, else the CPU), donating the parameters and
 optimizer state it replaces.  The loop spans one device; inside-step
 distribution belongs to XLA (see DESIGN.md §2).
+
+Every stage of a step is a span in the flight recorder
+(``repro.core.tracing.flight_recorder``, or the ``tracer=`` given), each
+carrying its ``step`` and mirrored into the profiler's trace when a
+profiler session runs:
+
+  * ``train.run`` — one ``run``: its Runtime's start, every task, the final
+    sync and the Runtime's shutdown;
+  * ``train.prefetch`` — the prefetch task: ``data.batch`` (the pipeline's
+    ``local_batch``) and the write into ``stage``;
+  * ``train.step`` — the step task (the profiler's step annotation):
+    ``train.stage_read`` (the batch out of ``stage``), ``train.dispatch``
+    (the jitted step enqueued) and ``train.loss_wait`` (the wait for the
+    loss, the one host sync per step), then reporting the loss;
+  * ``train.ckpt`` — the checkpoint task;
+  * counter ``runtime.instructions`` — instructions the Runtime executed,
+    one sample per ``run``.
 """
 
 from __future__ import annotations
 
+import functools
 import queue as _queue
 from dataclasses import dataclass, field
 from typing import Optional
@@ -33,6 +51,7 @@ from repro.checkpoint import CheckpointManager
 from repro.core import (Box, Runtime, fixed, one_to_one, read, read_write,
                         write)
 from repro.core.task_graph import TaskType
+from repro.core.tracing import Tracer, flight_recorder
 from repro.data import SyntheticLMData
 from repro.launch.steps import make_train_step
 from repro.models import build_model
@@ -50,10 +69,20 @@ class TrainMetrics:
         self.losses.append(float(loss))
 
 
+def _annotation(name: str, step: int):
+    """The profiler's annotation of a trainer span.  The step task is the
+    profiler's step, numbered, so TensorBoard's step view reads it too; the
+    other spans leave the number to the flight recorder, which is cheaper."""
+    if name == "train.step":
+        return jax.profiler.StepTraceAnnotation(name, step_num=step)
+    return jax.profiler.TraceAnnotation(name)
+
+
 class TrainLoop:
     def __init__(self, cfg, *, global_batch: int, seq_len: int,
                  ckpt_dir=None, ckpt_interval: int = 50, lr: float = 3e-4,
-                 prefetch_depth: int = 2, seed: int = 0):
+                 prefetch_depth: int = 2, seed: int = 0,
+                 tracer: Optional[Tracer] = None):
         self.cfg = cfg
         self.global_batch = global_batch
         self.seq_len = seq_len
@@ -65,6 +94,10 @@ class TrainLoop:
                      if ckpt_dir else None)
         self.train_step = jax.jit(make_train_step(self.model, lr=lr),
                                   donate_argnums=(0, 1))
+        self.tracer = tracer if tracer is not None else flight_recorder()
+        # scope(lane, name, step=t); a lane per task, as tasks overlap
+        self._scope = functools.partial(self.tracer.scope,
+                                        annotate=_annotation)
 
     # -- state ------------------------------------------------------------------
     def init_state(self, seed: int = 0):
@@ -106,7 +139,9 @@ class TrainLoop:
         return start_step + num_steps, holder["state"], metrics
 
     def _run_body(self, num_steps, start_step, holder, results, fail_at):
-        with Runtime(num_nodes=1, devices_per_node=1, trace=True) as rt:
+        scope = self._scope
+        with scope("run", "train.run", step=start_step), \
+                Runtime(num_nodes=1, devices_per_node=1) as rt:
             B = self.global_batch
             stage = rt.buffer((self.depth, B, self.seq_len), dtype=np.int32,
                               name="stage",
@@ -120,23 +155,31 @@ class TrainLoop:
 
             for t in range(start_step, start_step + num_steps):
                 def prefetch(chunk, v, t=t):
-                    batch = self.data.local_batch(t)
-                    v.set(slot_region(t), batch["tokens"][None])
+                    with scope("prefetch", "train.prefetch", step=t):
+                        with scope("prefetch", "data.batch", step=t):
+                            batch = self.data.local_batch(t)
+                        v.set(slot_region(t), batch["tokens"][None])
 
                 rt.submit(f"prefetch{t}", (1,),
                           [write(stage, fixed(slot_region(t)))],
                           prefetch, ttype=TaskType.HOST)
 
                 def step_fn(chunk, v, tok, t=t):
-                    toks = np.asarray(v.get(slot_region(t))[0])
-                    if fail_at is not None and t == fail_at:
-                        raise RuntimeError(f"injected failure at step {t}")
-                    batch = {"tokens": toks, "labels": toks}
-                    s = holder["state"]
-                    p, o, m = self.train_step(s["params"], s["opt"], batch)
-                    holder["state"] = {"params": p, "opt": o}
-                    results.put((t, float(m["loss"])))
-                    tok[0] = float(t)
+                    with scope("step", "train.step", step=t):
+                        with scope("step", "train.stage_read", step=t):
+                            toks = np.asarray(v.get(slot_region(t))[0])
+                        if fail_at is not None and t == fail_at:
+                            raise RuntimeError(f"injected failure at step {t}")
+                        batch = {"tokens": toks, "labels": toks}
+                        s = holder["state"]
+                        with scope("step", "train.dispatch", step=t):
+                            p, o, m = self.train_step(s["params"], s["opt"],
+                                                      batch)
+                        holder["state"] = {"params": p, "opt": o}
+                        with scope("step", "train.loss_wait", step=t):
+                            loss = float(m["loss"])
+                        results.put((t, loss))
+                        tok[0] = float(t)
 
                 rt.submit(f"step{t}", (1,),
                           [read(stage, fixed(slot_region(t))),
@@ -145,14 +188,15 @@ class TrainLoop:
 
                 if self.ckpt is not None and self.ckpt.should_save(t):
                     def ckpt_fn(chunk, tok, t=t):
-                        self.ckpt.save(t, holder["state"])
+                        with scope("ckpt", "train.ckpt", step=t):
+                            self.ckpt.save(t, holder["state"])
 
                     rt.submit(f"ckpt{t}", (1,),
                               [read(token, one_to_one())],
                               ckpt_fn, ttype=TaskType.HOST)
             rt.sync(timeout=600)
-            self.overlap = (rt.tracer.overlap_fraction("N0.host", "N0.host")
-                            if rt.tracer else 0.0)
+            self.tracer.counter("runtime.instructions",
+                                rt.metrics()["executor"][0]["done"])
 
 
 def train(cfg, *, steps: int, global_batch: int, seq_len: int,
