@@ -9,10 +9,12 @@ from __future__ import annotations
 
 import jax
 
+from repro.core.tracing import flight_recorder
+
 from . import ref
 from .flash_attention import flash_attention_tpu
 from .nbody import nbody_forces_tpu
-from .ssd_scan import ssd_scan_tpu
+from .ssd_scan import ssd
 from .stencil5 import wave_step_tpu
 
 
@@ -42,8 +44,13 @@ def wave_step(um, u, *, c=0.25, interpret=None):
 
 
 def ssd_scan(x, a, B, C, *, chunk=64, interpret=None):
-    if on_tpu() or interpret:
-        return ssd_scan_tpu(x, a, B, C, chunk=chunk,
-                            interpret=bool(interpret) and not on_tpu())
+    """(y, final state) of the SSD, differentiable on either path.  Each
+    trace records ``ssd.kernel`` in the flight recorder: 1 where the site
+    is built with the Pallas op, 0 where it falls back to jnp."""
+    kernel = on_tpu() or bool(interpret)
+    flight_recorder().counter("ssd.kernel", int(kernel))
+    if kernel:
+        return ssd(x, a, B, C, chunk=chunk,
+                   interpret=bool(interpret) and not on_tpu())
     from repro.models.mamba2 import ssd_chunked
     return ssd_chunked(x, a, B, C, chunk)

@@ -13,6 +13,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops
+
 from . import layers as L
 from .config import ArchConfig
 
@@ -146,7 +148,10 @@ class Mamba2LM:
         z, xBC, dt = jnp.split(zxbcdt, [di, di + self.conv_dim], axis=-1)
         return z, xBC, dt
 
-    def _block_seq(self, lp, x):
+    def _block_seq(self, lp, x, ssd=None):
+        """One block over whole sequences.  ``ssd`` is the SSD scan, by
+        default ``kernels.ops.ssd_scan``: the Pallas op on the TPU, the jnp
+        scan elsewhere."""
         cfg = self.cfg
         Bsz, S, _ = x.shape
         di, n, h = self.d_inner, cfg.ssm_state, self.nheads
@@ -161,8 +166,8 @@ class Mamba2LM:
         a = (dt * A).astype(jnp.float32)                              # log-decay
         xh = xs.reshape(Bsz, S, h, self.headdim)
         xin = xh * dt.astype(x.dtype)[..., None]
-        y, _ = ssd_chunked(xin, a, Bm.astype(x.dtype), Cm.astype(x.dtype),
-                           cfg.ssm_chunk)
+        y, _ = (ssd or ops.ssd_scan)(xin, a, Bm.astype(x.dtype),
+                                     Cm.astype(x.dtype), chunk=cfg.ssm_chunk)
         y = y + xh * lp["D"].astype(x.dtype)[:, None]
         y = y.reshape(Bsz, S, di)
         with jax.named_scope("norm"):

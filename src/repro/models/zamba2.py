@@ -14,7 +14,7 @@ import jax.numpy as jnp
 
 from . import layers as L
 from .config import ArchConfig
-from .mamba2 import CONV_WIDTH, Mamba2LM
+from .mamba2 import CONV_WIDTH, Mamba2LM, causal_conv, ssd_chunked
 from .transformer import stack_layer_params
 
 
@@ -69,7 +69,9 @@ class Zamba2LM:
         sp = params["shared"]
 
         def inner(x, lp):
-            return self.mamba._block_seq(lp, x), None
+            # the jnp scan on every backend: the Pallas op is tuned and
+            # compiled for mamba2-370m's widths only
+            return self.mamba._block_seq(lp, x, ssd=ssd_chunked), None
 
         inner_fn = jax.checkpoint(inner) if cfg.remat else inner
 
@@ -146,7 +148,6 @@ class Zamba2LM:
         """One mamba layer forward capturing (conv tail, final ssm state)."""
         cfg = self.cfg
         m = self.mamba
-        from .mamba2 import causal_conv, ssd_chunked
         Bsz, S, _ = x.shape
         di, n, h = m.d_inner, cfg.ssm_state, m.nheads
         hin = L.rms_norm(lp["ln"], x, cfg.norm_eps)

@@ -1,6 +1,8 @@
 """Pallas kernel validation: shape/dtype sweeps in interpret=True against the
 pure-jnp oracles in kernels/ref.py."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,7 +11,7 @@ import pytest
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_tpu
 from repro.kernels.nbody import nbody_forces_tpu
-from repro.kernels.ssd_scan import ssd_scan_tpu
+from repro.kernels.ssd_scan import ssd
 from repro.kernels.stencil5 import wave_step_tpu
 from repro.models.mamba2 import ssd_chunked
 
@@ -92,12 +94,86 @@ def test_ssd_scan(s, chunk, h, p, n, dtype):
     a = -jax.nn.softplus(jax.random.normal(ks[1], (b, s, h)))
     B = jax.random.normal(ks[2], (b, s, n), dtype)
     C = jax.random.normal(ks[3], (b, s, n), dtype)
-    y, st = ssd_scan_tpu(x, a, B, C, chunk=chunk, interpret=True)
-    ye, ste = ssd_chunked(x, a, B, C, chunk)
+    y, st = ssd(x, a, B, C, chunk=chunk, interpret=True)
+    ye, ste = jax.jit(ssd_chunked, static_argnums=4)(x, a, B, C, chunk)
     np.testing.assert_allclose(np.asarray(y), np.asarray(ye),
                                rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(np.asarray(st), np.asarray(ste),
                                rtol=2e-4, atol=2e-4)
+
+
+SSD_GRAD_SHAPES = [                 # (s, chunk, h, p, n)
+    (64, 16, 2, 8, 4),              # heads narrower than a lane tile
+    (40, 16, 1, 16, 8),             # padding to a chunk multiple, h = 1
+    (128, 32, 4, 64, 16),           # two heads per 128-lane block, 2 blocks
+]
+
+
+@functools.lru_cache(maxsize=None)
+def _ssd_grads(fn, dtype, s, chunk, h, p, n):
+    """Gradients of a weighted sum of (y, final state) w.r.t. x, a, B, C,
+    with x, B, C given to ``fn`` in ``dtype``.  x, B, C and the weights lie
+    on the bf16 grid, so a bf16 run's miss is its products' rounding and
+    its outputs', not its inputs'."""
+    ks = jax.random.split(jax.random.PRNGKey(1), 6)
+    b = 2
+
+    def normal(k, shape):
+        return jax.random.normal(k, shape).astype(jnp.bfloat16).astype(
+            jnp.float32)
+
+    x = normal(ks[0], (b, s, h, p))
+    a = -jax.nn.softplus(jax.random.normal(ks[1], (b, s, h)))
+    B = normal(ks[2], (b, s, n))
+    C = normal(ks[3], (b, s, n))
+    wy = normal(ks[4], (b, s, h, p))
+    wh = normal(ks[5], (b, h, p, n))
+
+    def loss(x, a, B, C):
+        y, hlast = fn(x.astype(dtype), a, B.astype(dtype), C.astype(dtype),
+                      chunk)
+        return jnp.sum(y.astype(jnp.float32) * wy) + jnp.sum(hlast * wh)
+
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3)))(x, a, B, C)
+
+
+def _pallas_ssd(x, a, B, C, chunk):
+    return ssd(x, a, B, C, chunk=chunk, interpret=True)
+
+
+def _rel_err(got, want):
+    return float(jnp.max(jnp.abs(got.astype(jnp.float32) - want))
+                 / jnp.max(jnp.abs(want)))
+
+
+def _rel_norm_err(got, want):
+    return float(jnp.linalg.norm(got.astype(jnp.float32) - want)
+                 / jnp.linalg.norm(want))
+
+
+@pytest.mark.parametrize("s,chunk,h,p,n", SSD_GRAD_SHAPES)
+def test_ssd_grads(s, chunk, h, p, n):
+    """dx, da, dB, dC of the Pallas op against jax.grad through the jnp
+    scan, in f32."""
+    got = _ssd_grads(_pallas_ssd, jnp.float32, s, chunk, h, p, n)
+    want = _ssd_grads(ssd_chunked, jnp.float32, s, chunk, h, p, n)
+    for name, g, w in zip("x a B C".split(), got, want):
+        assert _rel_err(g, w) < 2e-4, name
+
+
+@pytest.mark.parametrize("s,chunk,h,p,n", SSD_GRAD_SHAPES)
+def test_ssd_grads_bf16(s, chunk, h, p, n):
+    """At bf16 inputs each of the op's gradients misses the f32 one by no
+    more than the jnp scan's own bf16 gradient of the same leaf misses it.
+    The misses are norms over the leaf: the largest single element's miss
+    is about one bf16 step of the output, whatever the products did.  (The
+    op reads 0.92 of the jnp scan's miss at most here; one bf16 pass for
+    the kernel's f32 operands read 1.08 to 2.4.)"""
+    want = _ssd_grads(ssd_chunked, jnp.float32, s, chunk, h, p, n)
+    jnp_bf16 = _ssd_grads(ssd_chunked, jnp.bfloat16, s, chunk, h, p, n)
+    got = _ssd_grads(_pallas_ssd, jnp.bfloat16, s, chunk, h, p, n)
+    for name, g, j, w in zip("x a B C".split(), got, jnp_bf16, want):
+        assert _rel_norm_err(g, w) <= _rel_norm_err(j, w), name
 
 
 def test_ssd_chunk_ref_single():

@@ -178,6 +178,58 @@ def test_grads_finite(family, kw):
         assert bool(jnp.isfinite(leaf).all())
 
 
+def test_mamba2_pallas_ssd_matches_jnp(monkeypatch):
+    """Mamba2LM's loss and parameter gradients with its SSD through the
+    Pallas op (interpret mode) equal the jnp scan's, in f32, on a sequence
+    that is not a chunk multiple; ``ssd.kernel`` in the flight recorder
+    names the path each trace took."""
+    from dataclasses import replace
+    from functools import partial
+
+    from repro.configs import get_config
+    from repro.core.tracing import flight_recorder
+    from repro.kernels import ops
+
+    cfg = replace(get_config("mamba2-370m").reduced(), dtype="float32")
+    m = build_model(cfg)
+    p = m.init(jax.random.PRNGKey(0))
+    ids = jax.random.randint(jax.random.PRNGKey(1), (2, 40), 0, cfg.vocab_size)
+    batch = {"tokens": ids, "labels": ids}
+
+    def loss_and_grads():
+        out = jax.jit(jax.value_and_grad(m.loss))(p, batch)
+        return out, flight_recorder().counters["ssd.kernel"][-1][1]
+
+    (l_jnp, g_jnp), engaged = loss_and_grads()
+    assert engaged == 0
+    monkeypatch.setattr(ops, "ssd_scan", partial(ops.ssd_scan, interpret=True))
+    (l_pallas, g_pallas), engaged = loss_and_grads()
+    assert engaged == 1
+    np.testing.assert_allclose(float(l_pallas), float(l_jnp), rtol=1e-5)
+    for got, want in zip(jax.tree.leaves(g_pallas), jax.tree.leaves(g_jnp)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-4,
+                                   atol=2e-4 * float(jnp.max(jnp.abs(want))))
+
+
+def test_zamba2_keeps_the_jnp_ssd(monkeypatch):
+    """Zamba2's mamba blocks run the jnp scan on every backend: they never
+    reach ``kernels.ops.ssd_scan``, which picks the Pallas op on the TPU."""
+    from repro.kernels import ops
+    from repro.models.zamba2 import Zamba2LM
+
+    def no_kernel(*args, **kwargs):
+        raise AssertionError("zamba2 reached kernels.ops.ssd_scan")
+
+    monkeypatch.setattr(ops, "ssd_scan", no_kernel)
+    cfg = tiny("hybrid", ssm_state=16, attn_every=2, num_layers=4)
+    m = build_model(cfg)
+    assert isinstance(m, Zamba2LM)
+    p = m.init(jax.random.PRNGKey(0))
+    ids = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, cfg.vocab_size)
+    assert bool(jnp.isfinite(m.loss(p, {"tokens": ids, "labels": ids})))
+
+
 def test_whisper_loss_and_shapes():
     cfg = ArchConfig("w", "audio", 4, 384, 6, 6, 1536, 51865, rope_theta=0.0,
                      tie_embeddings=True, enc_layers=4).reduced()

@@ -24,13 +24,15 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels.flash_attention import flash_attention_tpu
 from repro.kernels.nbody import nbody_forces_tpu
-from repro.kernels.ssd_scan import ssd_scan_tpu
+from repro.kernels import ops
+from repro.kernels.ssd_scan import ssd
 from repro.kernels.stencil5 import wave_step_tpu
 from repro.launch.steps import make_train_step
 from repro.models import build_model
 from repro.optim import adamw_init
 
 HBM_BYTES = 16 * 2**30          # one v5e chip
+SSD_CHUNK = get_config("mamba2-370m").ssm_chunk
 
 
 @pytest.fixture(scope="module")
@@ -71,11 +73,25 @@ def test_wave_step_compiles_at_8192_square(one_chip):
     _compile_kernel(wave_step_tpu, f, f)
 
 
-def test_ssd_scan_compiles_at_mamba2_370m_widths(one_chip):
+def _ssd_specs(sharding, dtype):
     b, s, h, p, n = 2, 2048, 32, 64, 128
-    _compile_kernel(lambda x, a, B, C: ssd_scan_tpu(x, a, B, C, chunk=64),
-                    _spec(one_chip, (b, s, h, p)), _spec(one_chip, (b, s, h)),
-                    _spec(one_chip, (b, s, n)), _spec(one_chip, (b, s, n)))
+    return (_spec(sharding, (b, s, h, p), dtype), _spec(sharding, (b, s, h)),
+            _spec(sharding, (b, s, n), dtype), _spec(sharding, (b, s, n), dtype))
+
+
+def test_ssd_scan_compiles_at_mamba2_370m_widths(one_chip):
+    _compile_kernel(lambda x, a, B, C: ssd(x, a, B, C, chunk=SSD_CHUNK),
+                    *_ssd_specs(one_chip, jnp.float32))
+
+
+def test_ssd_backward_compiles_at_mamba2_370m_widths(one_chip):
+    def loss(x, a, B, C):
+        y, hlast = ssd(x, a, B, C, chunk=SSD_CHUNK)
+        return jnp.sum(y.astype(jnp.float32)) + jnp.sum(hlast)
+
+    exe = _compile_kernel(jax.grad(loss, argnums=(0, 1, 2, 3)),
+                          *_ssd_specs(one_chip, jnp.bfloat16))
+    assert "ssd_bwd" in exe.as_text()
 
 
 def test_flash_attention_compiles_at_qwen2_1_5b_widths(one_chip):
@@ -86,10 +102,11 @@ def test_flash_attention_compiles_at_qwen2_1_5b_widths(one_chip):
                     _spec(one_chip, (b, s, k, hd), jnp.bfloat16))
 
 
-def test_mamba2_370m_train_step_fits_one_chip(one_chip):
+def test_mamba2_370m_train_step_fits_one_chip(one_chip, monkeypatch):
     """Full widths, depth cut to 2 layers to keep the compile short; the
     layers are a scan, so depth scales the parameter bytes and not the
-    program."""
+    program.  The SSD takes the chip's path, the Pallas op."""
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
     cfg = dataclasses.replace(get_config("mamba2-370m"), num_layers=2)
     model = build_model(cfg)
 
@@ -102,6 +119,7 @@ def test_mamba2_370m_train_step_fits_one_chip(one_chip):
     step = jax.jit(make_train_step(model), donate_argnums=(0, 1))
     exe = step.lower(on_chip(params), on_chip(opt),
                      {"tokens": toks, "labels": toks}).compile()
+    assert "ssd_bwd" in exe.as_text()
     ma = exe.memory_analysis()
     used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
