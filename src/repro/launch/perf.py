@@ -169,8 +169,6 @@ CELLS = {
         ("baseline", {}),
         ("+flash_attention", dict(flash_attention=True)),
         ("+bf16_params", dict(flash_attention=True, param_dtype="bfloat16")),
-        ("+moe_group_2048", dict(flash_attention=True,
-                                 param_dtype="bfloat16", moe_group=2048)),
         ("+mesh_32x8_ep8", dict(flash_attention=True, param_dtype="bfloat16",
                                 _mesh=(32, 8))),
         ("+mesh_64x4_ep4", dict(flash_attention=True, param_dtype="bfloat16",

@@ -27,10 +27,14 @@ class ArchConfig:
     tie_embeddings: bool = False
     norm_eps: float = 1e-5
     mlp: str = "swiglu"              # swiglu | gelu
-    # MoE
+    # MoE: dropless top-k routing over num_experts
     num_experts: int = 0
     top_k: int = 0
-    capacity_factor: float = 1.25
+    # Granite's scalars; None leaves the term out of the traced program
+    embedding_multiplier: Optional[float] = None  # scales embedded inputs
+    attention_multiplier: Optional[float] = None  # softmax scale (1/sqrt(hd))
+    residual_multiplier: Optional[float] = None   # scales each residual branch
+    logits_scaling: Optional[float] = None        # divides the logits
     # SSM (mamba2 / zamba2)
     ssm_state: int = 0
     ssm_heads: int = 0               # mamba2 value heads
@@ -49,7 +53,6 @@ class ArchConfig:
     scan_layers: bool = True
     # perf knobs (§Perf hillclimbs; defaults = paper-faithful baseline)
     flash_attention: bool = False    # fused blockwise attention everywhere
-    moe_group: int = 512             # MoE dispatch group size
     ablate_attention: bool = False   # measurement-only: zero out attention
                                      # mixing to isolate non-attention traffic
 

@@ -53,9 +53,9 @@ class InternVLModel:
         return logits[:, Tv:], aux
 
     def loss(self, params, batch):
-        logits, aux = self.forward(params, batch)
+        logits, _ = self.forward(params, batch)
         return L.cross_entropy(logits[:, :-1], batch["labels"][:, 1:],
-                               batch.get("mask", None)) + 0.01 * aux
+                               batch.get("mask", None))
 
     # -- decode: delegate to the LM with a multimodal prefill ---------------------
     def prefill(self, params, vis, ids, max_len: int):

@@ -14,6 +14,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops as kops
+
 # ---------------------------------------------------------------------------
 # initializers
 
@@ -98,7 +100,6 @@ def _sdpa(q, k, v, mask, *, use_kernel: bool = False, causal: bool = False,
     """
     S, T = q.shape[1], k.shape[1]
     if use_kernel and S > 1:
-        from repro.kernels import ops as kops
         return kops.flash_attention(q, k, v, causal=causal, window=window)
     if causal and S > 1 and S * T > FLASH_THRESHOLD:
         from ..kernels.ref import flash_attention_ref
@@ -110,6 +111,15 @@ def _sdpa(q, k, v, mask, *, use_kernel: bool = False, causal: bool = False,
     w = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     out = jnp.einsum("bkgst,btkh->bskgh", w, v)
     return out
+
+
+def scale_queries(cfg, q):
+    """Queries for a softmax scale of ``cfg.attention_multiplier``: every
+    attention path scales by 1/sqrt(hd), so the queries take the rest
+    (Granite's 1/64 at hd 64 is 0.125 here, exact in bf16)."""
+    if cfg.attention_multiplier is None:
+        return q
+    return q * (cfg.attention_multiplier * math.sqrt(q.shape[-1]))
 
 
 def causal_mask(S: int, T: int, offset: int = 0,
@@ -146,7 +156,7 @@ def attention(p, cfg, x, positions, mask, kv=None, *, use_kernel=False,
         k = apply_rope(k, positions, cfg.rope_theta) if cfg.rope_theta else k
     else:
         k, v = kv
-    qg = q.reshape(B, S, K, G, hd)
+    qg = scale_queries(cfg, q).reshape(B, S, K, G, hd)
     out = _sdpa(qg, k, v, mask, use_kernel=use_kernel, causal=causal,
                 window=cfg.sliding_window)
     out = out.reshape(B, S, H * hd)
@@ -179,7 +189,7 @@ def mlp(p, cfg, x):
 
 
 # ---------------------------------------------------------------------------
-# Mixture of Experts (GShard-style grouped top-k dispatch with capacity)
+# Mixture of Experts (dropless top-k, grouped expert products)
 
 
 def init_moe(key, cfg):
@@ -195,64 +205,61 @@ def init_moe(key, cfg):
     }
 
 
-def moe(p, cfg, x, *, group_size: int = 512):
-    """Top-k routed MoE with per-group expert capacity (token dropping).
+@jax.custom_vjp
+def permute_rows(x, idx, inv):
+    """``x[idx]`` for a permutation ``idx`` of x's rows whose inverse is
+    ``inv``: a gather, and its gradient the gather by ``inv`` (where
+    autodiff would scatter)."""
+    return x.at[idx].get(mode="promise_in_bounds", unique_indices=True)
 
-    Tokens are processed in groups of ``group_size`` so the dispatch tensor
-    [Gs, E, C] stays VMEM-friendly; experts run as one batched einsum over
-    the leading expert dim — the layout that shards naturally over an
-    expert-parallel mesh axis.
-    Returns (output, aux_loss).
-    """
+
+def _permute_rows_fwd(x, idx, inv):
+    return permute_rows(x, idx, inv), (idx, inv)
+
+
+def _permute_rows_bwd(res, g):
+    idx, inv = res
+    return permute_rows(g, inv, idx), None, None
+
+
+permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+def moe(p, cfg, x):
+    """Dropless top-k MoE, routed as Granite's ``GraniteMoeTopKGating``:
+    router logits in f32, each token's top ``top_k`` experts, its gates a
+    softmax over those k logits.  The token's k (token, slot) rows are
+    sorted by expert, the SwiGLU experts run as grouped products over each
+    expert's rows, and the k outputs are put back and summed under their
+    gates.  Sorting and putting back are gathers both ways
+    (``permute_rows``).  Every token reaches all k of its experts: there
+    is no capacity."""
     B, S, D = x.shape
     E, K = cfg.num_experts, cfg.top_k
     tokens = x.reshape(-1, D)
-    N = tokens.shape[0]
-    Gs = min(group_size, N)
-    assert N % Gs == 0, f"token count {N} not divisible by group {Gs}"
-    G = N // Gs
-    C = max(1, int(math.ceil(K * Gs / E * cfg.capacity_factor)))
-    xg = tokens.reshape(G, Gs, D)
-
-    logits = (xg.astype(jnp.float32) @ p["router"]["w"])       # [G,Gs,E]
-    probs = jax.nn.softmax(logits, axis=-1)
-
-    # load-balancing aux loss (Switch): E * mean(frac_tokens * frac_probs)
-    top1 = jnp.argmax(probs, axis=-1)
-    frac_tokens = jnp.mean(jax.nn.one_hot(top1, E, dtype=jnp.float32), axis=1)
-    frac_probs = jnp.mean(probs, axis=1)
-    aux = E * jnp.mean(jnp.sum(frac_tokens * frac_probs, axis=-1))
-
-    # iterative top-k with capacity assignment
-    combine = jnp.zeros((G, Gs, E, C), dtype=jnp.float32)
-    remaining = probs
-    fill = jnp.zeros((G, E), dtype=jnp.int32)                   # slots used
-    for _ in range(K):
-        idx = jnp.argmax(remaining, axis=-1)                    # [G,Gs]
-        gate = jnp.take_along_axis(remaining, idx[..., None], -1)[..., 0]
-        onehot = jax.nn.one_hot(idx, E, dtype=jnp.float32)      # [G,Gs,E]
-        pos = jnp.cumsum(onehot, axis=1) - onehot               # pos within group
-        pos = pos + fill[:, None, :]                            # offset by filled
-        in_cap = pos < C
-        slot = jnp.einsum("gse,gse->gs", onehot, pos).astype(jnp.int32)
-        keep = jnp.einsum("gse,gse->gs", onehot, in_cap.astype(jnp.float32)) > 0
-        cslot = jax.nn.one_hot(jnp.clip(slot, 0, C - 1), C, dtype=jnp.float32)
-        combine = combine + (gate * keep)[..., None, None] * \
-            onehot[..., None] * cslot[:, :, None, :]
-        fill = fill + jnp.sum(onehot * in_cap, axis=1).astype(jnp.int32)
-        remaining = remaining * (1.0 - onehot)
-
-    # renormalize kept gates over the k choices (granite-style top-k softmax)
-    denom = jnp.sum(combine, axis=(2, 3), keepdims=True) + 1e-9
-    combine = combine / denom
-    dispatch = (combine > 0).astype(x.dtype)                    # [G,Gs,E,C]
-
-    xin = jnp.einsum("gsec,gsd->egcd", dispatch, xg)            # [E,G,C,D]
-    h = jax.nn.silu(jnp.einsum("egcd,edf->egcf", xin, p["wg"].astype(x.dtype)))
-    h = h * jnp.einsum("egcd,edf->egcf", xin, p["wi"].astype(x.dtype))
-    out_e = jnp.einsum("egcf,efd->egcd", h, p["wo"].astype(x.dtype))
-    out = jnp.einsum("gsec,egcd->gsd", combine.astype(x.dtype), out_e)
-    return out.reshape(B, S, D), aux
+    with jax.named_scope("router"):
+        logits = jnp.dot(tokens.astype(jnp.float32), p["router"]["w"],
+                         precision=jax.lax.Precision.HIGHEST)      # [T,E]
+        top, idx = jax.lax.top_k(logits, K)                        # [T,K]
+        gates = jax.nn.softmax(top, axis=-1)
+    with jax.named_scope("moe_dispatch"):
+        expert = idx.reshape(-1)                                   # [T*K]
+        n = expert.shape[0]
+        order = jnp.argsort(expert, stable=True)
+        back = jnp.zeros((n,), jnp.int32).at[order].set(
+            jnp.arange(n, dtype=jnp.int32))
+        rows = permute_rows(jnp.repeat(tokens, K, axis=0), order, back)
+        # a sum of one-hots: bincount's scatter-add serializes on repeated
+        # experts, so its time would follow the routing
+        group_sizes = jnp.sum(jax.nn.one_hot(expert, E, dtype=jnp.int32), 0)
+    with jax.named_scope("experts"):
+        y = kops.grouped_swiglu(rows, p["wg"].astype(x.dtype),
+                                p["wi"].astype(x.dtype),
+                                p["wo"].astype(x.dtype), group_sizes)
+    with jax.named_scope("moe_combine"):
+        y = permute_rows(y, back, order).reshape(-1, K, D)
+        out = jnp.einsum("tkd,tk->td", y.astype(jnp.float32), gates)
+    return out.astype(x.dtype).reshape(B, S, D)
 
 
 # ---------------------------------------------------------------------------

@@ -48,36 +48,56 @@ class DecoderLM:
         return params
 
     # -- blocks -----------------------------------------------------------------
+    def _residual(self, x, y):
+        m = self.cfg.residual_multiplier
+        return x + (y if m is None else y * m)
+
     def _block(self, p, x, positions, mask, kv=None, *, use_kernel=None,
                causal=False):
         cfg = self.cfg
         if use_kernel is None:
             use_kernel = cfg.flash_attention
-        a, new_kv = L.attention(p["attn"], cfg, L.rms_norm(p["ln1"], x,
-                                                           cfg.norm_eps),
-                                positions, mask, kv=kv, use_kernel=use_kernel,
-                                causal=causal)
-        x = x + a
-        h = L.rms_norm(p["ln2"], x, cfg.norm_eps)
+        with jax.named_scope("norm"):
+            h = L.rms_norm(p["ln1"], x, cfg.norm_eps)
+        with jax.named_scope("attention"):
+            a, new_kv = L.attention(p["attn"], cfg, h, positions, mask, kv=kv,
+                                    use_kernel=use_kernel, causal=causal)
+        x = self._residual(x, a)
+        with jax.named_scope("norm"):
+            h = L.rms_norm(p["ln2"], x, cfg.norm_eps)
         if cfg.family == "moe":
-            y, aux = L.moe(p["moe"], cfg, h, group_size=cfg.moe_group)
+            y = L.moe(p["moe"], cfg, h)
         else:
-            y, aux = L.mlp(p["mlp"], cfg, h), 0.0
-        return x + y, aux, new_kv
+            y = L.mlp(p["mlp"], cfg, h)
+        return self._residual(x, y), new_kv
+
+    def _embed(self, params, ids):
+        cfg = self.cfg
+        with jax.named_scope("embed"):
+            x = L.embed(params["embed"], ids)
+            if cfg.embedding_multiplier is not None:
+                x = x * cfg.embedding_multiplier
+            return x.astype(cfg.adt)
 
     def _logits(self, params, x):
         cfg = self.cfg
-        x = L.rms_norm(params["ln_f"], x, cfg.norm_eps)
-        if cfg.tie_embeddings:
-            return L.unembed(params["embed"], x)
-        return L.linear(params["head"], x).astype(jnp.float32)
+        with jax.named_scope("norm"):
+            x = L.rms_norm(params["ln_f"], x, cfg.norm_eps)
+        with jax.named_scope("unembed"):
+            if cfg.tie_embeddings:
+                logits = L.unembed(params["embed"], x)
+            else:
+                logits = L.linear(params["head"], x).astype(jnp.float32)
+            if cfg.logits_scaling is not None:
+                logits = logits / cfg.logits_scaling
+            return logits
 
     # -- full forward (train / prefill) -------------------------------------------
     def forward(self, params, ids, *, return_cache: bool = False,
                 last_only: bool = False):
         cfg = self.cfg
         B, S = ids.shape
-        x = L.embed(params["embed"], ids).astype(cfg.adt)
+        x = self._embed(params, ids)
         positions = jnp.arange(S)
         mask = L.causal_mask(S, S, window=cfg.sliding_window)
         return self.forward_embedded(params, x, positions, mask,
@@ -91,32 +111,30 @@ class DecoderLM:
         matmul + vocab-axis collective behind it)."""
         cfg = self.cfg
 
-        def body(carry, lp):
-            x, aux = carry
-            x, a, kv = self._block(lp, x, positions, mask, causal=True)
-            out = kv if return_cache else 0
-            return (x, aux + a), out
+        def body(x, lp):
+            x, kv = self._block(lp, x, positions, mask, causal=True)
+            return x, (kv if return_cache else 0)
 
         body_fn = jax.checkpoint(body) if cfg.remat else body
         if cfg.scan_layers:
-            (x, aux), kvs = jax.lax.scan(body_fn, (x, 0.0), params["layers"])
+            x, kvs = jax.lax.scan(body_fn, x, params["layers"])
         else:
             kvs_list = []
             for i in range(cfg.num_layers):
                 lp = jax.tree.map(lambda v: v[i], params["layers"])
-                (x, aux), kv = body_fn((x, 0.0 if i == 0 else aux), lp)
+                x, kv = body_fn(x, lp)
                 kvs_list.append(kv)
             kvs = kvs_list if return_cache else None
         logits = self._logits(params, x[:, -1:] if last_only else x)
         if return_cache:
-            return logits, aux, kvs
-        return logits, aux
+            return logits, 0.0, kvs
+        return logits, 0.0
 
     def loss(self, params, batch):
-        logits, aux = self.forward(params, batch["tokens"])
-        ce = L.cross_entropy(logits[:, :-1], batch["labels"][:, 1:],
-                             batch.get("mask", None))
-        return ce + 0.01 * aux
+        logits, _ = self.forward(params, batch["tokens"])
+        with jax.named_scope("cross_entropy"):
+            return L.cross_entropy(logits[:, :-1], batch["labels"][:, 1:],
+                                   batch.get("mask", None))
 
     # -- cached decode --------------------------------------------------------------
     def cache_len(self, max_len: int) -> int:
@@ -161,7 +179,7 @@ class DecoderLM:
         pos = cache["pos"]
         W = cache["k"].shape[2]
         slot = pos % W
-        x = L.embed(params["embed"], ids).astype(cfg.adt)
+        x = self._embed(params, ids)
         positions = pos[None].astype(jnp.int32)
 
         kpos = cache["kpos"].at[slot].set(pos)
@@ -184,15 +202,16 @@ class DecoderLM:
             k_l = jax.lax.dynamic_update_slice_in_dim(k_l, kn, slot, axis=1)
             v_l = jax.lax.dynamic_update_slice_in_dim(v_l, vn, slot, axis=1)
             G = cfg.num_heads // K
-            qg = q.reshape(B, 1, K, G, hd)
+            qg = L.scale_queries(cfg, q).reshape(B, 1, K, G, hd)
             o = L._sdpa(qg, k_l, v_l, mask)
-            x = x + L.linear(lp["attn"]["wo"], o.reshape(B, 1, cfg.num_heads * hd))
+            x = self._residual(x, L.linear(lp["attn"]["wo"],
+                                           o.reshape(B, 1, cfg.num_heads * hd)))
             h2 = L.rms_norm(lp["ln2"], x, cfg.norm_eps)
             if cfg.family == "moe":
-                y, _ = L.moe(lp["moe"], cfg, h2, group_size=B)
+                y = L.moe(lp["moe"], cfg, h2)
             else:
                 y = L.mlp(lp["mlp"], cfg, h2)
-            return (x + y, 0.0), (k_l, v_l)
+            return (self._residual(x, y), 0.0), (k_l, v_l)
 
         (x, _), (k_new, v_new) = jax.lax.scan(
             body, (x, 0.0), (params["layers"], cache["k"], cache["v"]))

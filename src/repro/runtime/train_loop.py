@@ -31,7 +31,12 @@ profiler session runs:
   * ``train.step`` — the step task (the profiler's step annotation):
     ``train.stage_read`` (the batch out of ``stage``), ``train.dispatch``
     (the jitted step enqueued) and ``train.loss_wait`` (the wait for the
-    loss, the one host sync per step), then reporting the loss;
+    previous step's loss, the one host sync per step), then reporting that
+    loss.  The wait comes after this step is enqueued, so the device has
+    the next step queued whenever it finishes one, and the host's turn
+    between steps (the wake-up on the loss, the Runtime issuing the next
+    task, the dispatch) is hidden behind the step; the last step's loss is
+    waited for once the Runtime has synced;
   * ``train.ckpt`` — the checkpoint task;
   * counter ``runtime.instructions`` — instructions the Runtime executed,
     one sample per ``run``.
@@ -140,6 +145,13 @@ class TrainLoop:
 
     def _run_body(self, num_steps, start_step, holder, results, fail_at):
         scope = self._scope
+        pending = []                   # the (step, loss) not yet on the host
+
+        def settle():
+            if pending:
+                t, loss = pending.pop()
+                results.put((t, float(loss)))
+
         with scope("run", "train.run", step=start_step), \
                 Runtime(num_nodes=1, devices_per_node=1) as rt:
             B = self.global_batch
@@ -166,19 +178,22 @@ class TrainLoop:
 
                 def step_fn(chunk, v, tok, t=t):
                     with scope("step", "train.step", step=t):
-                        with scope("step", "train.stage_read", step=t):
-                            toks = np.asarray(v.get(slot_region(t))[0])
-                        if fail_at is not None and t == fail_at:
-                            raise RuntimeError(f"injected failure at step {t}")
-                        batch = {"tokens": toks, "labels": toks}
-                        s = holder["state"]
-                        with scope("step", "train.dispatch", step=t):
-                            p, o, m = self.train_step(s["params"], s["opt"],
-                                                      batch)
-                        holder["state"] = {"params": p, "opt": o}
-                        with scope("step", "train.loss_wait", step=t):
-                            loss = float(m["loss"])
-                        results.put((t, loss))
+                        try:
+                            with scope("step", "train.stage_read", step=t):
+                                toks = np.asarray(v.get(slot_region(t))[0])
+                            if fail_at is not None and t == fail_at:
+                                raise RuntimeError(
+                                    f"injected failure at step {t}")
+                            batch = {"tokens": toks, "labels": toks}
+                            s = holder["state"]
+                            with scope("step", "train.dispatch", step=t):
+                                p, o, m = self.train_step(s["params"],
+                                                          s["opt"], batch)
+                            holder["state"] = {"params": p, "opt": o}
+                        finally:
+                            with scope("step", "train.loss_wait", step=t):
+                                settle()
+                        pending.append((t, m["loss"]))
                         tok[0] = float(t)
 
                 rt.submit(f"step{t}", (1,),
@@ -195,6 +210,7 @@ class TrainLoop:
                               [read(token, one_to_one())],
                               ckpt_fn, ttype=TaskType.HOST)
             rt.sync(timeout=600)
+            settle()
             self.tracer.counter("runtime.instructions",
                                 rt.metrics()["executor"][0]["done"])
 

@@ -56,12 +56,14 @@ def tiny(family, **kw):
     ("dense", {}),
     ("dense", {"sliding_window": 8}),
     ("dense", {"qkv_bias": True}),
-    # capacity_factor high enough that no token drops: capacity-based MoE
-    # routing is only prefix-consistent when nothing is dropped
-    ("moe", {"num_experts": 4, "top_k": 2, "capacity_factor": 16.0}),
+    ("moe", {"num_experts": 4, "top_k": 2}),
     ("ssm", {"num_heads": 0, "num_kv_heads": 0, "d_ff": 0,
              "ssm_state": 16, "tie_embeddings": True}),
     ("hybrid", {"ssm_state": 16, "attn_every": 2, "num_layers": 4}),
+    # Granite's scalars (softmax scale 1/32 at head width 32, not 1/sqrt)
+    ("moe", {"num_experts": 4, "top_k": 2, "embedding_multiplier": 12.0,
+             "attention_multiplier": 1 / 32, "residual_multiplier": 0.22,
+             "logits_scaling": 6.0}),
 ])
 def test_decode_matches_forward(family, kw):
     """prefill + N decode steps must reproduce teacher-forced logits."""
@@ -104,24 +106,39 @@ def test_sliding_window_decode_ring_buffer():
 
 # -- MoE invariants -------------------------------------------------------------
 def test_moe_routing_weights_normalized():
+    """With every expert the same, each token's gated sum over its top-k
+    experts is that one expert's output: the gates sum to 1."""
     cfg = tiny("moe", num_experts=8, top_k=2, d_model=64, d_ff=32)
     key = jax.random.PRNGKey(0)
     p = L.init_moe(key, cfg)
+    p = dict(p, **{k: jnp.broadcast_to(p[k][:1], p[k].shape)
+                   for k in ("wg", "wi", "wo")})
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 64))
-    out, aux = L.moe(p, cfg, x, group_size=16)
+    out = L.moe(p, cfg, x)
     assert out.shape == x.shape
-    assert float(aux) >= 1.0 - 1e-3    # aux loss lower bound is 1 (balanced)
     assert not bool(jnp.isnan(out).any())
+    xt = x.reshape(-1, 64)
+    one = (jax.nn.silu(xt @ p["wg"][0]) * (xt @ p["wi"][0])) @ p["wo"][0]
+    np.testing.assert_allclose(np.asarray(out).reshape(one.shape),
+                               np.asarray(one), rtol=1e-4, atol=1e-5)
 
 
-def test_moe_capacity_drops_tokens_gracefully():
-    from dataclasses import replace
-    cfg = replace(tiny("moe", num_experts=4, top_k=2, d_model=64, d_ff=32),
-                  capacity_factor=0.25)   # aggressively small capacity
+def test_moe_is_dropless_when_every_token_picks_one_expert():
+    """A router that sends every token to the same first expert: each
+    token still gets all of its top-k experts' outputs, as if routed
+    alone (there is no capacity to overflow)."""
+    cfg = tiny("moe", num_experts=4, top_k=2, d_model=64, d_ff=32)
     p = L.init_moe(jax.random.PRNGKey(0), cfg)
-    x = jax.random.normal(jax.random.PRNGKey(1), (1, 32, 64))
-    out, _ = L.moe(p, cfg, x, group_size=32)
+    w = jnp.zeros_like(p["router"]["w"]).at[:, 0].set(1.0).at[:, 1].set(0.5)
+    p = dict(p, router={"w": w})
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(1), (1, 32, 64)))
+    out = L.moe(p, cfg, x)
+    alone = jnp.concatenate([L.moe(p, cfg, x[:, t:t + 1])
+                             for t in range(32)], axis=1)
     assert not bool(jnp.isnan(out).any())
+    assert bool(jnp.all(jnp.abs(out).sum(-1) > 0))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(alone),
+                               rtol=1e-5, atol=1e-6)
 
 
 # -- attention variants -----------------------------------------------------------

@@ -102,6 +102,27 @@ def test_flash_attention_compiles_at_qwen2_1_5b_widths(one_chip):
                     _spec(one_chip, (b, s, k, hd), jnp.bfloat16))
 
 
+def test_grouped_swiglu_compiles_at_granite_widths(one_chip, monkeypatch):
+    """One granite-moe-1b-a400m layer's expert products at the benchmark
+    cell's shape (4096 tokens x top-8 rows, d 1024, F 512, 32 experts),
+    forward and gradient, through the Pallas grouped matmul."""
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    m, d, F, E = 4096 * 8, 1024, 512, 32
+
+    def loss(rows, wg, wi, wo, gs):
+        y = ops.grouped_swiglu(rows, wg, wi, wo, gs)
+        return jnp.sum(y.astype(jnp.float32))
+
+    bf = jnp.bfloat16
+    exe = _compile_kernel(jax.grad(loss, argnums=(0, 1, 2, 3)),
+                          _spec(one_chip, (m, d), bf),
+                          _spec(one_chip, (E, d, F), bf),
+                          _spec(one_chip, (E, d, F), bf),
+                          _spec(one_chip, (E, F, d), bf),
+                          _spec(one_chip, (E,), jnp.int32))
+    assert "tgmm" in exe.as_text()
+
+
 def test_mamba2_370m_train_step_fits_one_chip(one_chip, monkeypatch):
     """Full widths, depth cut to 2 layers to keep the compile short; the
     layers are a scan, so depth scales the parameter bytes and not the

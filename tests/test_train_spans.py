@@ -142,3 +142,37 @@ def test_a_trainer_writes_into_the_flight_recorder_by_default():
     loop = TrainLoop(CFG, global_batch=2, seq_len=64)
     assert loop.tracer is flight_recorder() is flight_recorder()
     assert loop.tracer.spans.maxlen == FLIGHT_RECORDER_CAPACITY
+
+
+def test_each_step_is_enqueued_before_the_previous_loss_is_read():
+    """The loss wait trails the dispatch by one step: step t is enqueued
+    before step t-1's loss is read on the host, so the device has the
+    next step queued whenever it finishes one.  Losses still come back
+    in order, each with its own step."""
+    events = []
+
+    class Loss:
+        def __init__(self, t):
+            self.t = t
+
+        def __float__(self):
+            events.append(("read", self.t))
+            return float(self.t)
+
+    loop = TrainLoop(CFG, global_batch=2, seq_len=64, lr=1e-4,
+                     tracer=Tracer())
+    calls = iter(range(STEPS))
+
+    def step(params, opt, batch):
+        t = next(calls)
+        events.append(("dispatch", t))
+        return params, opt, {"loss": Loss(t)}
+
+    loop.train_step = step
+    state = {"params": {}, "opt": {}}
+    _, _, m = loop.run(STEPS, start_step=0, state=state)
+    assert m.steps == list(range(STEPS))
+    assert m.losses == [float(t) for t in range(STEPS)]
+    assert events == [("dispatch", 0)] + [
+        e for t in range(1, STEPS) for e in (("dispatch", t), ("read", t - 1))
+    ] + [("read", STEPS - 1)]
