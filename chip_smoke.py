@@ -30,7 +30,7 @@ import numpy as np  # noqa: E402
 from repro.configs import get_config  # noqa: E402
 from repro.core.tracing import flight_recorder  # noqa: E402
 from repro.kernels import ops, ref  # noqa: E402
-from repro.kernels.flash_attention import flash_attention_tpu  # noqa: E402
+from repro.kernels.flash_attention import causal_attention  # noqa: E402
 from repro.kernels.nbody import nbody_forces_tpu  # noqa: E402
 from repro.kernels.ssd_scan import ssd  # noqa: E402
 from repro.kernels.stencil5 import wave_step_tpu  # noqa: E402
@@ -186,7 +186,7 @@ def _flash(b, s, k, g, hd, interpret):
     q = jax.random.normal(ks[0], (b, s, k, g, hd), jnp.bfloat16)
     kk = jax.random.normal(ks[1], (b, s, k, hd), jnp.bfloat16)
     v = jax.random.normal(ks[2], (b, s, k, hd), jnp.bfloat16)
-    fn = functools.partial(flash_attention_tpu, interpret=interpret)
+    fn = functools.partial(causal_attention, interpret=interpret)
     out = _compiled(fn, q, kk, v, interpret=interpret)(q, kk, v)
     f32 = [t.astype(jnp.float32) for t in (q, kk, v)]
     return [(out.astype(jnp.float32), _reference(ref.flash_attention_ref, *f32))]

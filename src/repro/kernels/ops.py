@@ -14,7 +14,7 @@ from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
 from repro.core.tracing import flight_recorder
 
 from . import ref
-from .flash_attention import flash_attention_tpu
+from . import flash_attention as fused
 from .nbody import nbody_forces_tpu
 from .ssd_scan import ssd
 from .stencil5 import wave_step_tpu
@@ -24,11 +24,14 @@ def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def flash_attention(q, k, v, *, causal=True, window=None, interpret=None):
+def flash_attention(q, k, v, *, window=None, interpret=None):
+    """Causal self-attention, q [B,S,K,G,hd], k/v [B,S,K,hd], at any S,
+    differentiable: the fused Pallas op on the TPU (or in interpret mode),
+    which pads S to whole tiles, the blockwise jnp ref elsewhere."""
     if on_tpu() or interpret:
-        return flash_attention_tpu(q, k, v, causal=causal, window=window,
-                                   interpret=bool(interpret) and not on_tpu())
-    return ref.flash_attention_ref(q, k, v, causal=causal, window=window)
+        return fused.causal_attention(
+            q, k, v, window=window, interpret=bool(interpret) and not on_tpu())
+    return ref.flash_attention_ref(q, k, v, causal=True, window=window)
 
 
 def nbody_forces(p_all, *, soft=1e-3, interpret=None):

@@ -145,34 +145,23 @@ def run_variants(arch: str, shape: str, variants: list[tuple[str, dict]],
 CELLS = {
     "qwen_train": lambda: run_variants("qwen2_1_5b", "train_4k", [
         ("baseline", {}),
-        ("+flash_attention", dict(flash_attention=True)),
-        ("+bf16_params", dict(flash_attention=True, param_dtype="bfloat16")),
-        ("+no_remat", dict(flash_attention=True, param_dtype="bfloat16",
-                           remat=False)),
-        ("+mesh_32x8", dict(flash_attention=True, param_dtype="bfloat16",
-                            _mesh=(32, 8))),
-        ("+mesh_64x4", dict(flash_attention=True, param_dtype="bfloat16",
-                            _mesh=(64, 4))),
-        ("+mesh_128x2", dict(flash_attention=True, param_dtype="bfloat16",
-                             _mesh=(128, 2))),
-        ("+mesh_256x1_pure_dp", dict(flash_attention=True,
-                                     param_dtype="bfloat16", _mesh=(256, 1))),
+        ("+bf16_params", dict(param_dtype="bfloat16")),
+        ("+no_remat", dict(param_dtype="bfloat16", remat=False)),
+        ("+mesh_32x8", dict(param_dtype="bfloat16", _mesh=(32, 8))),
+        ("+mesh_64x4", dict(param_dtype="bfloat16", _mesh=(64, 4))),
+        ("+mesh_128x2", dict(param_dtype="bfloat16", _mesh=(128, 2))),
+        ("+mesh_256x1_pure_dp", dict(param_dtype="bfloat16", _mesh=(256, 1))),
     ], project_kernel_from="+mesh_128x2"),
     "minitron_prefill": lambda: run_variants("minitron_4b", "prefill_32k", [
         ("baseline", {}),
-        ("+flash_attention", dict(flash_attention=True)),
-        ("+bf16_params", dict(flash_attention=True, param_dtype="bfloat16")),
-        ("+mesh_32x8", dict(flash_attention=True, param_dtype="bfloat16",
-                            _mesh=(32, 8))),
+        ("+bf16_params", dict(param_dtype="bfloat16")),
+        ("+mesh_32x8", dict(param_dtype="bfloat16", _mesh=(32, 8))),
     ], project_kernel_from="+mesh_32x8"),
     "granite_train": lambda: run_variants("granite_moe_3b_a800m", "train_4k", [
         ("baseline", {}),
-        ("+flash_attention", dict(flash_attention=True)),
-        ("+bf16_params", dict(flash_attention=True, param_dtype="bfloat16")),
-        ("+mesh_32x8_ep8", dict(flash_attention=True, param_dtype="bfloat16",
-                                _mesh=(32, 8))),
-        ("+mesh_64x4_ep4", dict(flash_attention=True, param_dtype="bfloat16",
-                                _mesh=(64, 4))),
+        ("+bf16_params", dict(param_dtype="bfloat16")),
+        ("+mesh_32x8_ep8", dict(param_dtype="bfloat16", _mesh=(32, 8))),
+        ("+mesh_64x4_ep4", dict(param_dtype="bfloat16", _mesh=(64, 4))),
     ], project_kernel_from="+mesh_32x8_ep8"),
 }
 

@@ -52,7 +52,6 @@ class ArchConfig:
     remat: bool = True
     scan_layers: bool = True
     # perf knobs (§Perf hillclimbs; defaults = paper-faithful baseline)
-    flash_attention: bool = False    # fused blockwise attention everywhere
     ablate_attention: bool = False   # measurement-only: zero out attention
                                      # mixing to isolate non-attention traffic
 

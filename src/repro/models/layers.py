@@ -14,6 +14,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core.tracing import flight_recorder
 from repro.kernels import ops as kops
 
 # ---------------------------------------------------------------------------
@@ -87,20 +88,25 @@ def init_attention(key, cfg):
 FLASH_THRESHOLD = 4096 * 4096   # S*T above which blockwise attention is used
 
 
-def _sdpa(q, k, v, mask, *, use_kernel: bool = False, causal: bool = False,
+def _sdpa(q, k, v, mask, *, causal: bool = False,
           window: Optional[int] = None):
     """Grouped scaled-dot-product attention.
 
     q: [B,S,K,G,hd] (G = query groups per kv head), k/v: [B,T,K,hd],
     mask: [B,1,S,T] or broadcastable boolean (True = attend).
 
-    Large S*T (long-context prefill) automatically takes the blockwise
-    flash path so O(S*T) logits are never materialized; the Pallas TPU
-    kernel is selected by ``use_kernel`` (see kernels/ops.py).
+    On the TPU, causal self-attention (``causal``, S == T > 1) takes the
+    fused op of ``kops.flash_attention`` at any S.  Otherwise large S*T
+    (long-context prefill) takes the blockwise jnp path so O(S*T) logits are
+    never materialized, and the rest the einsum-softmax-einsum path.  Each
+    trace records ``attention.kernel`` in the flight recorder: 1 where the
+    site is built with the fused Pallas op, 0 otherwise.
     """
     S, T = q.shape[1], k.shape[1]
-    if use_kernel and S > 1:
-        return kops.flash_attention(q, k, v, causal=causal, window=window)
+    fused = causal and S == T > 1 and kops.on_tpu()
+    flight_recorder().counter("attention.kernel", int(fused))
+    if fused:
+        return kops.flash_attention(q, k, v, window=window)
     if causal and S > 1 and S * T > FLASH_THRESHOLD:
         from ..kernels.ref import flash_attention_ref
         return flash_attention_ref(q, k, v, causal=True, window=window)
@@ -134,8 +140,7 @@ def causal_mask(S: int, T: int, offset: int = 0,
     return m
 
 
-def attention(p, cfg, x, positions, mask, kv=None, *, use_kernel=False,
-              causal=False):
+def attention(p, cfg, x, positions, mask, kv=None, *, causal=False):
     """kv: optional (k, v) override for cross-attention / cached decode."""
     B, S, d = x.shape
     if getattr(cfg, "ablate_attention", False) and kv is None:
@@ -157,7 +162,7 @@ def attention(p, cfg, x, positions, mask, kv=None, *, use_kernel=False,
     else:
         k, v = kv
     qg = scale_queries(cfg, q).reshape(B, S, K, G, hd)
-    out = _sdpa(qg, k, v, mask, use_kernel=use_kernel, causal=causal,
+    out = _sdpa(qg, k, v, mask, causal=causal and kv is None,
                 window=cfg.sliding_window)
     out = out.reshape(B, S, H * hd)
     return linear(p["wo"], out), (k, v)

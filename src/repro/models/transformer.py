@@ -52,16 +52,13 @@ class DecoderLM:
         m = self.cfg.residual_multiplier
         return x + (y if m is None else y * m)
 
-    def _block(self, p, x, positions, mask, kv=None, *, use_kernel=None,
-               causal=False):
+    def _block(self, p, x, positions, mask, kv=None, *, causal=False):
         cfg = self.cfg
-        if use_kernel is None:
-            use_kernel = cfg.flash_attention
         with jax.named_scope("norm"):
             h = L.rms_norm(p["ln1"], x, cfg.norm_eps)
         with jax.named_scope("attention"):
             a, new_kv = L.attention(p["attn"], cfg, h, positions, mask, kv=kv,
-                                    use_kernel=use_kernel, causal=causal)
+                                    causal=causal)
         x = self._residual(x, a)
         with jax.named_scope("norm"):
             h = L.rms_norm(p["ln2"], x, cfg.norm_eps)

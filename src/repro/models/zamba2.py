@@ -52,8 +52,7 @@ class Zamba2LM:
         cfg = self.cfg
         a, new_kv = L.attention(sp["attn"], cfg,
                                 L.rms_norm(sp["ln1"], x, cfg.norm_eps),
-                                positions, mask, kv=kv, causal=(kv is None),
-                                use_kernel=cfg.flash_attention)
+                                positions, mask, kv=kv, causal=(kv is None))
         x = x + a
         x = x + L.mlp(sp["mlp"], cfg, L.rms_norm(sp["ln2"], x, cfg.norm_eps))
         return x, new_kv

@@ -8,11 +8,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ref
-from repro.kernels.flash_attention import flash_attention_tpu
+from repro.core.tracing import flight_recorder
+from repro.kernels import flash_attention as fused
+from repro.kernels import ops, ref
 from repro.kernels.nbody import nbody_forces_tpu
 from repro.kernels.ssd_scan import ssd
 from repro.kernels.stencil5 import wave_step_tpu
+from repro.models.layers import _sdpa, causal_mask
 from repro.models.mamba2 import ssd_chunked
 
 
@@ -21,42 +23,92 @@ def tol(dtype):
            dict(atol=2e-5, rtol=2e-5)
 
 
-# -- flash attention ----------------------------------------------------------
-@pytest.mark.parametrize("S,T,K,G,hd", [
-    (64, 64, 2, 3, 32), (128, 128, 1, 4, 64), (48, 96, 2, 1, 16),
-    (256, 256, 4, 2, 128),
+# -- fused causal attention --------------------------------------------------
+def _attention_inputs(S, K, G, hd, dtype, seed=0):
+    """q with Granite's query scale (0.125 at hd 64, a power of two), k, v,
+    and the weights of a loss over the output."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q = jax.random.normal(ks[0], (2, S, K, G, hd)) * 0.125 * 4
+    k = jax.random.normal(ks[1], (2, S, K, hd))
+    v = jax.random.normal(ks[2], (2, S, K, hd))
+    w = jax.random.normal(ks[3], (2, S, K, G, hd))
+    return [x.astype(dtype) for x in (q, k, v)] + [w]
+
+
+def _unfused(S, window=None):
+    """``_sdpa``'s einsum-softmax-einsum path under the causal mask."""
+    mask = causal_mask(S, S, window=window)
+    return lambda q, k, v: _sdpa(q, k, v, mask)
+
+
+def _out_and_grads(fn, q, k, v, w):
+    def loss(q, k, v):
+        out = fn(q, k, v)
+        return jnp.sum(out.astype(jnp.float32) * w), out
+
+    (_, out), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    return (out,) + grads
+
+
+ATTENTION_CASES = [             # (S, K, G, window): 4 over 2 and 16 over 8
+    (256, 2, 2, None), (512, 2, 2, None), (256, 8, 2, None),
+    (512, 8, 2, None), (512, 2, 2, 200), (256, 8, 2, 100),
+    (200, 2, 2, None),          # no whole number of tiles: padded
+]
+
+
+@pytest.mark.parametrize("S,K,G,window", ATTENTION_CASES)
+def test_fused_attention_matches_unfused(monkeypatch, S, K, G, window):
+    """Causal self-attention through ``_sdpa`` where the TPU is faked and
+    the fused op runs in interpret mode, against the unfused path, in bf16
+    at hd 64: the output and the gradients of q, k and v miss the f32
+    unfused path's by no more than the bf16 unfused path's do (norms over
+    each tensor), also where S is padded to whole tiles.  The site records
+    ``attention.kernel`` 1."""
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    monkeypatch.setattr(ops, "flash_attention", functools.partial(
+        fused.causal_attention, interpret=True))
+    q, k, v, w = _attention_inputs(S, K, G, 64, jnp.bfloat16)
+    mask = causal_mask(S, S, window=window)
+
+    def sdpa(q, k, v):
+        return _sdpa(q, k, v, mask, causal=True, window=window)
+
+    got = _out_and_grads(sdpa, q, k, v, w)
+    assert flight_recorder().counters["attention.kernel"][-1][1] == 1
+    bf16 = _out_and_grads(_unfused(S, window), q, k, v, w)
+    f32 = _out_and_grads(_unfused(S, window),
+                         *(x.astype(jnp.float32) for x in (q, k, v)), w)
+    for name, g, b, f in zip(("out", "dq", "dk", "dv"), got, bf16, f32):
+        assert _rel_norm_err(g, f) <= _rel_norm_err(b, f), name
+
+
+@pytest.mark.parametrize("S,K,G,hd", [
+    (64, 2, 3, 32), (128, 1, 4, 64), (48, 2, 1, 16), (256, 4, 2, 128),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("causal,window", [(True, None), (True, 32),
-                                           (False, None)])
-def test_flash_attention(S, T, K, G, hd, dtype, causal, window):
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    B = 2
-    q = jax.random.normal(ks[0], (B, S, K, G, hd), dtype)
-    k = jax.random.normal(ks[1], (B, T, K, hd), dtype)
-    v = jax.random.normal(ks[2], (B, T, K, hd), dtype)
-    out = flash_attention_tpu(q, k, v, causal=causal, window=window,
-                              q_block=32, kv_block=32, interpret=True)
-    exp = ref.flash_attention_ref(q.astype(jnp.float32), k.astype(jnp.float32),
-                                  v.astype(jnp.float32), causal=causal,
-                                  window=window)
+@pytest.mark.parametrize("window", [None, 32])
+def test_flash_attention_any_length(S, K, G, hd, dtype, window):
+    """The fused op of ``ops.flash_attention`` at any S (padded to whole
+    tiles): its output against the blockwise f32 reference."""
+    q, k, v, _ = _attention_inputs(S, K, G, hd, dtype)
+    out = ops.flash_attention(q, k, v, window=window, interpret=True)
+    exp = ref.flash_attention_ref(*(x.astype(jnp.float32) for x in (q, k, v)),
+                                  causal=True, window=window)
     np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(exp),
                                **tol(dtype))
 
 
-def test_flash_attention_decode_offset():
-    """q_offset supports decode-style partial queries."""
-    ks = jax.random.split(jax.random.PRNGKey(1), 3)
-    B, S0, S1, K, G, hd = 1, 48, 16, 2, 2, 32
-    q_full = jax.random.normal(ks[0], (B, S0 + S1, K, G, hd))
-    k = jax.random.normal(ks[1], (B, S0 + S1, K, hd))
-    v = jax.random.normal(ks[2], (B, S0 + S1, K, hd))
-    full = ref.flash_attention_ref(q_full, k, v, causal=True)
-    part = flash_attention_tpu(q_full[:, S0:], k, v, causal=True,
-                               q_block=16, kv_block=16, interpret=True,
-                               q_offset=S0)
-    np.testing.assert_allclose(np.asarray(part), np.asarray(full[:, S0:]),
-                               atol=2e-5)
+def test_flash_attention_grads_any_length():
+    """The fused op's gradients at a length that is no whole tile equal
+    the blockwise reference's, in f32."""
+    q, k, v, w = _attention_inputs(200, 2, 2, 64, jnp.float32)
+    got = _out_and_grads(
+        functools.partial(ops.flash_attention, interpret=True), q, k, v, w)
+    want = _out_and_grads(ref.flash_attention_ref, q, k, v, w)
+    for g, e in zip(got, want):
+        assert _rel_err(g, e) < 2e-5
 
 
 # -- nbody ----------------------------------------------------------------------

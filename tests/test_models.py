@@ -274,3 +274,60 @@ def test_internvl_loss_and_shapes():
     logits, _ = m.forward(p, batch)
     assert logits.shape == (B, S, cfg.vocab_size)
     assert bool(jnp.isfinite(m.loss(p, batch)))
+
+
+def _granite_shaped():
+    from repro.configs import get_config
+    return get_config("granite-moe-1b-a400m").reduced(head_dim=64,
+                                                      dtype="bfloat16")
+
+
+def _trace_train(seq):
+    m = build_model(_granite_shaped())
+    p = jax.eval_shape(m.init, jax.random.PRNGKey(0))
+    ids = jax.ShapeDtypeStruct((1, seq), jnp.int32)
+    jax.make_jaxpr(jax.grad(m.loss))(p, {"tokens": ids, "labels": ids})
+
+
+def _trace_decode(seq):
+    m = build_model(_granite_shaped())
+    p = jax.eval_shape(m.init, jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: m.init_cache(1, seq))
+    jax.make_jaxpr(m.decode_step)(p, cache,
+                                  jax.ShapeDtypeStruct((1, 1), jnp.int32))
+
+
+def _trace_noncausal(seq):
+    cfg = _granite_shaped()
+    p = jax.eval_shape(lambda: L.init_attention(jax.random.PRNGKey(0), cfg))
+    x = jax.ShapeDtypeStruct((1, seq, cfg.d_model), jnp.bfloat16)
+    jax.make_jaxpr(lambda p, x: L.attention(
+        p, cfg, x, jnp.arange(seq), L.causal_mask(seq, seq))[0])(p, x)
+
+
+def _trace_mamba2(seq):
+    from repro.configs import get_config
+    m = build_model(get_config("mamba2-370m").reduced())
+    p = jax.eval_shape(m.init, jax.random.PRNGKey(0))
+    ids = jax.ShapeDtypeStruct((1, seq), jnp.int32)
+    jax.make_jaxpr(jax.grad(m.loss))(p, {"tokens": ids, "labels": ids})
+
+
+@pytest.mark.parametrize("trace,want", [
+    (_trace_train, [1]), (_trace_decode, [0]),
+    (_trace_noncausal, [0]), (_trace_mamba2, []),
+])
+def test_attention_kernel_selection(monkeypatch, trace, want):
+    """On a TPU (faked; the programs are traced, not run), at a length that
+    is no whole tile: ``DecoderLM``'s training forward at Granite-shaped
+    widths (GQA, hd 64, bf16) records ``attention.kernel`` 1 (its layers are
+    one scanned body, traced once), cached decode (S = 1) and a non-causal
+    call 0, and ``Mamba2LM``, which has no attention, nothing."""
+    from repro.core.tracing import flight_recorder
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    monkeypatch.setitem(flight_recorder().counters, "attention.kernel", [])
+    trace(200)
+    counters = flight_recorder().counters
+    assert [v for _, v in counters.get("attention.kernel", [])] == want

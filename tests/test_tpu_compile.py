@@ -15,6 +15,7 @@ chip.
 
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +23,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
-from repro.kernels.flash_attention import flash_attention_tpu
+from repro.kernels.flash_attention import causal_attention
 from repro.kernels.nbody import nbody_forces_tpu
 from repro.kernels import ops
 from repro.kernels.ssd_scan import ssd
@@ -94,12 +95,21 @@ def test_ssd_backward_compiles_at_mamba2_370m_widths(one_chip):
     assert "ssd_bwd" in exe.as_text()
 
 
-def test_flash_attention_compiles_at_qwen2_1_5b_widths(one_chip):
+def test_causal_attention_compiles_at_qwen2_1_5b_widths(one_chip):
+    """The fused attention, forward and gradient, at qwen2-1.5b's widths
+    (12 query heads in 2 KV groups, hd 128)."""
     b, s, k, g, hd = 2, 2048, 2, 6, 128
-    _compile_kernel(flash_attention_tpu,
-                    _spec(one_chip, (b, s, k, g, hd), jnp.bfloat16),
-                    _spec(one_chip, (b, s, k, hd), jnp.bfloat16),
-                    _spec(one_chip, (b, s, k, hd), jnp.bfloat16))
+
+    def loss(q, kk, v):
+        return jnp.sum(causal_attention(q, kk, v).astype(jnp.float32))
+
+    bf = jnp.bfloat16
+    exe = _compile_kernel(jax.grad(loss, argnums=(0, 1, 2)),
+                          _spec(one_chip, (b, s, k, g, hd), bf),
+                          _spec(one_chip, (b, s, k, hd), bf),
+                          _spec(one_chip, (b, s, k, hd), bf))
+    for kernel in ("splash_mha_fwd", "splash_mha_dkv", "splash_mha_dq"):
+        assert kernel in exe.as_text()
 
 
 def test_grouped_swiglu_compiles_at_granite_widths(one_chip, monkeypatch):
@@ -123,25 +133,47 @@ def test_grouped_swiglu_compiles_at_granite_widths(one_chip, monkeypatch):
     assert "tgmm" in exe.as_text()
 
 
+def _train_step_on_chip(sharding, cfg, batch, seq):
+    """The train step of ``cfg`` compiled for one chip, with its arguments'
+    and temporaries' bytes."""
+    model = build_model(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: _spec(sharding, s.shape, s.dtype), tree)
+
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    opt = jax.eval_shape(adamw_init, params)
+    toks = _spec(sharding, (batch, seq), jnp.int32)
+    step = jax.jit(make_train_step(model), donate_argnums=(0, 1))
+    exe = step.lower(on_chip(params), on_chip(opt),
+                     {"tokens": toks, "labels": toks}).compile()
+    ma = exe.memory_analysis()
+    used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    return exe, used
+
+
 def test_mamba2_370m_train_step_fits_one_chip(one_chip, monkeypatch):
     """Full widths, depth cut to 2 layers to keep the compile short; the
     layers are a scan, so depth scales the parameter bytes and not the
     program.  The SSD takes the chip's path, the Pallas op."""
     monkeypatch.setattr(ops, "on_tpu", lambda: True)
     cfg = dataclasses.replace(get_config("mamba2-370m"), num_layers=2)
-    model = build_model(cfg)
-
-    def on_chip(tree):
-        return jax.tree.map(lambda s: _spec(one_chip, s.shape, s.dtype), tree)
-
-    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    opt = jax.eval_shape(adamw_init, params)
-    toks = _spec(one_chip, (4, 1024), jnp.int32)
-    step = jax.jit(make_train_step(model), donate_argnums=(0, 1))
-    exe = step.lower(on_chip(params), on_chip(opt),
-                     {"tokens": toks, "labels": toks}).compile()
+    exe, used = _train_step_on_chip(one_chip, cfg, 4, 1024)
     assert "ssd_bwd" in exe.as_text()
-    ma = exe.memory_analysis()
-    used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
-            - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    assert 0 < used < HBM_BYTES
+
+
+def test_granite_moe_train_step_attention_is_fused(one_chip, monkeypatch):
+    """granite-moe-1b-a400m at full widths, depth cut to 2 layers, batch
+    1 x 4096: the train step compiles with the fused attention's forward,
+    dk/dv and dq kernels, fits the chip, and holds no [..., 4096, 4096]
+    buffer of scores or probabilities."""
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    cfg = dataclasses.replace(get_config("granite-moe-1b-a400m"), num_layers=2)
+    exe, used = _train_step_on_chip(one_chip, cfg, 1, 4096)
+    hlo = exe.as_text()
+    for kernel in ("splash_mha_fwd", "splash_mha_dkv", "splash_mha_dq"):
+        assert kernel in hlo
+    assert not re.findall(r"(?:bf16|f32)\[[\d,]*4096,4096[\d,]*\]", hlo)
     assert 0 < used < HBM_BYTES
